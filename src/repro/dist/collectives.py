@@ -241,16 +241,14 @@ def dist_add(
     build = lambda: AddExecutable(a, b)
     with tr.span("dist_add", cat="collective", nnzb_a=a.nnzb, nnzb_b=b.nnzb):
         exe = cache.get_or_build(key, build) if cache is not None else build()
-        with tr.span("dispatch", cat="kernel", op="add") as sp:
-            store = tr.sync(
-                exe(a.store, b.store, alpha, beta).astype(
-                    jnp.result_type(a.dtype, b.dtype)
-                )
+        with tr.span("dispatch", cat="kernel", kind="add") as sp:
+            store = exe(a.store, b.store, alpha, beta).astype(
+                jnp.result_type(a.dtype, b.dtype)
             )
-            if tr.enabled:
-                sp.worker_costs = np.bincount(
-                    exe.c_owner, minlength=a.nparts
-                ).astype(np.float64)
+        if tr.enabled:
+            sp.worker_costs = np.bincount(
+                exe.c_owner, minlength=a.nparts
+            ).astype(np.float64)
     return DistBSMatrix(
         shape=tuple(a.shape),
         bs=a.bs,
@@ -319,6 +317,7 @@ def dist_trace(a: DistBSMatrix, cache: PlanCache | None = None) -> float:
     with tr.span("dist_trace", cat="collective", nnzb=a.nnzb):
         key = ("trace", _structure_key(a))
         exe = cache.get_or_build(key, build) if cache is not None else build()
+        tr.counter("host_fetches").add()
         return float(exe(a.store))
 
 
@@ -331,6 +330,7 @@ def dist_frobenius_norm(a: DistBSMatrix, cache: PlanCache | None = None) -> floa
     with tr.span("dist_fro", cat="collective", nnzb=a.nnzb):
         key = ("fro", _structure_key(a))
         exe = cache.get_or_build(key, build) if cache is not None else build()
+        tr.counter("host_fetches").add()
         return float(np.sqrt(exe(a.store)))
 
 
@@ -442,6 +442,7 @@ def dist_truncate(
     # hierarchical path, which times only the descent)
     norms_sq = np.asarray(_block_norms_sq(a.store))  # [P, cap] -> host (small)
     if tr.enabled:
+        tr.counter("host_fetches").add()
         tr.counter("norm_fetch_bytes").add(a.nnzb * 4)
     with timed_into(cache, "symbolic_s", tr, "truncate_select",
                     cat="symbolic", nnzb=a.nnzb):
@@ -598,16 +599,16 @@ def dist_transpose(
     build = lambda: TransposeExecutable(a)
     with tr.span("dist_transpose", cat="collective", nnzb=a.nnzb):
         exe = cache.get_or_build(key, build) if cache is not None else build()
-        with tr.span("dispatch", cat="kernel", op="transpose") as sp:
-            store = tr.sync(exe(a.store))
-            if tr.enabled:
-                blk = a.bs * a.bs * a.store.dtype.itemsize
-                shipped = int(exe.sent_blocks.sum())
-                sp.args.update(sent_blocks=shipped)
-                tr.counter("send_bytes").add(shipped * blk)
-                tr.counter("recv_bytes").add(shipped * blk)
-                # cost share: blocks each source ships, plus the local gather
-                sp.worker_costs = exe.sent_blocks.astype(np.float64) + 1.0
+        with tr.span("dispatch", cat="kernel", kind="transpose") as sp:
+            store = exe(a.store)
+        if tr.enabled:
+            blk = a.bs * a.bs * a.store.dtype.itemsize
+            shipped = int(exe.sent_blocks.sum())
+            sp.args.update(sent_blocks=shipped)
+            tr.counter("send_bytes").add(shipped * blk)
+            tr.counter("recv_bytes").add(shipped * blk)
+            # cost share: blocks each source ships, plus the local gather
+            sp.worker_costs = exe.sent_blocks.astype(np.float64) + 1.0
     return DistBSMatrix(
         shape=(a.shape[1], a.shape[0]),
         bs=a.bs,
@@ -732,13 +733,13 @@ def dist_repartition(
             stats["migrated_blocks"] = exe.migrated_blocks
             stats["migrated_bytes"] = exe.migrated_blocks * blk
             stats["sent_blocks_per_worker"] = exe.sent_blocks.copy()
-        with tr.span("dispatch", cat="kernel", op="repartition") as sp:
-            store = tr.sync(exe(x.store))
-            if tr.enabled:
-                msp.args.update(migrated_blocks=exe.migrated_blocks)
-                tr.counter("migrated_bytes").add(exe.migrated_blocks * blk)
-                # cost share: blocks each source ships, plus the local gather
-                sp.worker_costs = exe.sent_blocks.astype(np.float64) + 1.0
+        with tr.span("dispatch", cat="kernel", kind="repartition") as sp:
+            store = exe(x.store)
+        if tr.enabled:
+            msp.args.update(migrated_blocks=exe.migrated_blocks)
+            tr.counter("migrated_bytes").add(exe.migrated_blocks * blk)
+            # cost share: blocks each source ships, plus the local gather
+            sp.worker_costs = exe.sent_blocks.astype(np.float64) + 1.0
     return DistBSMatrix(
         shape=tuple(x.shape),
         bs=x.bs,

@@ -200,6 +200,7 @@ def resident_block_norms(x: DistBSMatrix, cache=None) -> np.ndarray:
     tr = tracer_of(cache)
     with tr.span("norm_fetch", cat="collective", nnzb=x.nnzb):
         if tr.enabled:
+            tr.counter("host_fetches").add()
             tr.counter("norm_fetch_bytes").add(x.nnzb * 4)
         mm = getattr(cache, "memory_meter", None) if cache is not None else None
         if mm is not None:

@@ -415,9 +415,10 @@ def dist_multiply(
                 exe = make_spgemm_executable(plan, a.mesh, impl=staged)
             return plan, exe
 
-        key = multiply_plan_key(
-            a, b, exchange=exchange, impl=impl, precision=precision
-        )
+        with tr.span("plan_key", cat="plan"):
+            key = multiply_plan_key(
+                a, b, exchange=exchange, impl=impl, precision=precision
+            )
         if cache is None:
             plan, exe = build()
         else:
@@ -433,27 +434,27 @@ def dist_multiply(
             )
             task_on = _valid_task_slots(plan)
             task_low = _adaptive_low_table(plan, low_task)
-        with tr.span("dispatch", cat="kernel", op="spgemm") as sp:
+        with tr.span("dispatch", cat="kernel", kind="spgemm") as sp:
             if adaptive:
-                c_store = tr.sync(exe(a.store, b.store, task_on, task_low))
+                c_store = exe(a.store, b.store, task_on, task_low)
             else:
-                c_store = tr.sync(exe(a.store, b.store))
-            if tr.enabled:
-                _annotate_spgemm_dispatch(
-                    tr, sp, plan, plan.task_count, precision, exe
-                )
-    c = DistBSMatrix(
-        shape=(a.shape[0], b.shape[1]),
-        bs=a.bs,
-        coords=plan.c_coords,
-        owner=np.asarray(plan.c_owner, dtype=np.int32),
-        slot=np.asarray(plan.c_slot, dtype=np.int32),
-        cap=plan.c_cap,
-        store=c_store,
-        mesh=a.mesh,
-    )
-    _note_dispatch_memory(cache, plan, precision, c)
-    _note_dispatch_locality(cache, tr, plan, precision, a, b, exe=exe)
+                c_store = exe(a.store, b.store)
+        if tr.enabled:
+            _annotate_spgemm_dispatch(
+                tr, sp, plan, plan.task_count, precision, exe
+            )
+        c = DistBSMatrix(
+            shape=(a.shape[0], b.shape[1]),
+            bs=a.bs,
+            coords=plan.c_coords,
+            owner=np.asarray(plan.c_owner, dtype=np.int32),
+            slot=np.asarray(plan.c_slot, dtype=np.int32),
+            cap=plan.c_cap,
+            store=c_store,
+            mesh=a.mesh,
+        )
+        _note_dispatch_memory(cache, plan, precision, c)
+        _note_dispatch_locality(cache, tr, plan, precision, a, b, exe=exe)
     return c
 
 
@@ -576,9 +577,10 @@ def _dist_spamm_impl(
         tasks, err = _spamm_pruned_tasks(a, b, tau, a_norms, b_norms)
 
     if method == "delta":
-        key = spamm_delta_plan_key(
-            a, b, exchange=exchange, impl=impl, precision=precision
-        )
+        with tr.span("plan_key", cat="plan"):
+            key = spamm_delta_plan_key(
+                a, b, exchange=exchange, impl=impl, precision=precision
+            )
 
         def build():
             # the delta plan IS the exact-multiply plan; reuse one already
@@ -658,15 +660,15 @@ def _dist_spamm_impl(
         masked_count = task_on.sum(axis=1).astype(np.int64)
         if cache is not None:
             cache.last_task_count = masked_count
-        with tr.span("dispatch", cat="kernel", op="spamm-delta") as sp:
+        with tr.span("dispatch", cat="kernel", kind="spamm-delta") as sp:
             if fused:
-                c_store = tr.sync(exe(a.store, b.store, task_on, task_low))
+                c_store = exe(a.store, b.store, task_on, task_low)
             else:
-                c_store = tr.sync(exe(a.store, b.store, task_on))
-            if tr.enabled:
-                _annotate_spgemm_dispatch(
-                    tr, sp, plan, masked_count, precision, exe
-                )
+                c_store = exe(a.store, b.store, task_on)
+        if tr.enabled:
+            _annotate_spgemm_dispatch(
+                tr, sp, plan, masked_count, precision, exe
+            )
         c = DistBSMatrix(
             shape=(a.shape[0], b.shape[1]),
             bs=a.bs,
@@ -733,12 +735,12 @@ def _dist_spamm_impl(
         plan, exe = cache.get_or_build(key, build)
         cache.last_plan_key = key
         cache.last_task_count = plan.task_count
-    with tr.span("dispatch", cat="kernel", op="spamm-replan") as sp:
-        c_store = tr.sync(exe(a.store, b.store))
-        if tr.enabled:
-            _annotate_spgemm_dispatch(
-                tr, sp, plan, plan.task_count, precision, exe
-            )
+    with tr.span("dispatch", cat="kernel", kind="spamm") as sp:
+        c_store = exe(a.store, b.store)
+    if tr.enabled:
+        _annotate_spgemm_dispatch(
+            tr, sp, plan, plan.task_count, precision, exe
+        )
     c = DistBSMatrix(
         shape=(a.shape[0], b.shape[1]),
         bs=a.bs,

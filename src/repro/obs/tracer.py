@@ -18,7 +18,8 @@ service for the XLA-mesh reproduction:
 * **Counters and gauges** are registered once on the tracer's metrics
   registry (``plan_hits`` / ``plan_misses`` / ``tasks_executed`` /
   ``recv_bytes`` / ``send_bytes`` / ``migrated_bytes`` /
-  ``norm_fetch_bytes``, plus ``plans_verified`` / ``verify_violations``
+  ``norm_fetch_bytes`` / ``host_fetches`` (blocking device-to-host reads),
+  plus ``plans_verified`` / ``verify_violations``
   from the static verifier at plan-cache admission) and emitted uniformly:
   live as Chrome counter events, and at run end as the flat dict
   (:func:`run_metrics`) the driver stats dataclasses wrap.
@@ -27,6 +28,18 @@ service for the XLA-mesh reproduction:
   ``plan_verify_violation`` instant in category ``"analysis"`` carrying
   the check id and task/round provenance — query them with
   :meth:`Tracer.instants_of`.
+* **On the device trace's clock**: every span is also written into the JAX
+  profiler's trace as a ``jax.profiler.TraceAnnotation`` named
+  ``repro.<span name>``, so a profiler capture shows the library's host
+  work beside the device operations.  With no profiler running the
+  annotation is the profiler's own no-op.  A span measures host time: a
+  ``dispatch`` span covers the enqueue of an executable, and the device
+  time is read from the device trace.
+* **Compiles**: while a span is open, each XLA compile (a persistent-cache
+  load included) adds to the ``compiles`` / ``compile_s`` counters and
+  records a ``compile`` instant under the innermost span, carrying that
+  span's name and plan ``kind``.  Compiles outside the library's spans are
+  not counted.
 * :data:`NULL_TRACER` is the disabled tracer every un-instrumented call
   path sees: all methods are allocation-free no-ops, it is falsy, and it
   records nothing — tracing off costs a few attribute lookups per
@@ -36,12 +49,17 @@ The tracer rides on the plan cache (``SymbolicCache.tracer``), which is
 already threaded through every resident collective and driver — enable
 tracing by constructing ``PlanCache(tracer=Tracer())`` or by passing
 ``tracer=`` to a driver, and read it back anywhere via :func:`tracer_of`.
+:meth:`Tracer.close` unregisters its compile listener.
 """
 
 from __future__ import annotations
 
 import time
+import weakref
 from typing import Any
+
+import jax
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter",
@@ -53,6 +71,11 @@ __all__ = [
     "tracer_of",
     "run_metrics",
 ]
+
+#: prefix of the library's span names in a profiler trace
+SPAN_PREFIX = "repro."
+#: the jax.monitoring duration event of an XLA compile or persistent-cache load
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class Counter:
@@ -118,55 +141,50 @@ class Span:
 class _SpanHandle:
     """Context manager closing one span; yields the span for annotation."""
 
-    __slots__ = ("_tracer", "_span", "_jax_scope")
+    __slots__ = ("_tracer", "_span", "_scope")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
-        self._jax_scope = None
+        self._scope = TraceAnnotation(SPAN_PREFIX + span.name)
 
     def __enter__(self) -> Span:
-        if self._tracer._jax_scopes:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._jax_scope = TraceAnnotation(self._span.name)
-                self._jax_scope.__enter__()
-            except Exception:  # jax absent or profiler unavailable
-                self._jax_scope = None
+        self._scope.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> None:
-        if self._jax_scope is not None:
-            self._jax_scope.__exit__(*exc)
         tr = self._tracer
         self._span.t1 = tr._clock()
         tr._stack.pop()
+        self._scope.__exit__(*exc)
         return None
+
+
+def _compile_listener(ref: "weakref.ref[Tracer]"):
+    """A ``jax.monitoring`` duration listener that holds its tracer weakly,
+    so a tracer that is dropped without :meth:`Tracer.close` is not kept
+    alive by JAX's listener list."""
+
+    def on_duration(event: str, secs: float, **kw) -> None:
+        tr = ref()
+        if tr is not None and event == COMPILE_EVENT:
+            tr._on_compile(secs, kw.get("fun_name", ""))
+
+    return on_duration
 
 
 class Tracer:
     """Records nested spans, instants, and registered counters/gauges.
 
-    ``sync`` makes :meth:`sync` block on device values inside kernel-dispatch
-    spans so span durations measure execution rather than async dispatch
-    (numerics are untouched either way).  ``jax_scopes`` additionally opens a
-    ``jax.profiler.TraceAnnotation`` named scope per span, so a concurrent
-    ``jax.profiler.trace`` capture carries the same labels.
+    Spans measure host time on ``clock`` and are mirrored into the profiler
+    trace (module docstring).  Construction registers one ``jax.monitoring``
+    listener that counts compiles under open spans; :meth:`close` removes it.
     """
 
     enabled = True
 
-    def __init__(
-        self,
-        *,
-        clock=time.perf_counter,
-        sync: bool = True,
-        jax_scopes: bool = False,
-    ):
+    def __init__(self, *, clock=time.perf_counter):
         self._clock = clock
-        self._sync = sync
-        self._jax_scopes = jax_scopes
         self.origin = clock()
         self.spans: list[Span] = []
         self.instants: list[tuple[str, str, float, int, dict]] = []
@@ -174,6 +192,23 @@ class Tracer:
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._counter_events: list[tuple[float, str, float]] = []
+        self._listener = _compile_listener(weakref.ref(self))
+        jax.monitoring.register_event_duration_secs_listener(self._listener)
+
+    def close(self) -> None:
+        """Unregister the compile listener; spans and counters stay readable."""
+        if self._listener is not None:
+            jax.monitoring.unregister_event_duration_listener(self._listener)
+            self._listener = None
+
+    def _on_compile(self, secs: float, fun: str) -> None:
+        if not self._stack:
+            return
+        sp = self.spans[self._stack[-1]]
+        self.counter("compiles").add()
+        self.counter("compile_s").add(secs)
+        self.instant("compile", cat="compile", span=sp.name,
+                     kind=sp.args.get("kind"), fun=fun, secs=secs)
 
     def __bool__(self) -> bool:
         return True
@@ -217,22 +252,6 @@ class Tracer:
         out.update({name: g.value for name, g in sorted(self._gauges.items())})
         out["spans_recorded"] = len(self.spans)
         return out
-
-    # -- device sync ---------------------------------------------------------
-    def sync(self, x: Any) -> Any:
-        """Block on a device value so the enclosing span measures execution.
-
-        No-op when the tracer was built with ``sync=False`` (and always on
-        :data:`NULL_TRACER`), so tracing off never forces synchronization.
-        """
-        if self._sync:
-            try:
-                import jax
-
-                jax.block_until_ready(x)
-            except ImportError:
-                pass
-        return x
 
 
 class _NullHandle:
@@ -297,9 +316,6 @@ class NullTracer:
 
     def metrics_flat(self) -> dict:
         return {}
-
-    def sync(self, x: Any) -> Any:
-        return x
 
 
 NULL_TRACER = NullTracer()

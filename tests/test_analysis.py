@@ -185,7 +185,7 @@ def test_cache_rejects_corrupt_plan_and_traces_violations():
 
     plan = _plan()
     bad, _ = CORRUPTIONS["send_conflict"][0](plan)
-    tr = Tracer(sync=False)
+    tr = Tracer()
     cache = SymbolicCache(tracer=tr)
     with pytest.raises(PlanError) as exc:
         cache.get_or_build(("spgemm", "k1"), lambda: (bad, None))

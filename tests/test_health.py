@@ -124,7 +124,7 @@ def test_events_filter_by_name_and_level():
 
 
 def test_postmortem_golden_keys(tmp_path):
-    tr = Tracer(sync=False)
+    tr = Tracer()
     cache = SymbolicCache(tracer=tr, event_log=EventLog())
     rec = FlightRecorder(str(tmp_path / "pm.json")).install(cache)
     assert cache.flight_recorder is rec
@@ -137,7 +137,7 @@ def test_postmortem_golden_keys(tmp_path):
 
 
 def test_postmortem_counter_deltas_vs_mark(tmp_path):
-    tr = Tracer(sync=False)
+    tr = Tracer()
     cache = SymbolicCache(tracer=tr)
     rec = FlightRecorder(str(tmp_path / "pm.json")).install(cache)
     tr.counter("tasks_executed").add(10.0)
@@ -154,7 +154,7 @@ def test_plan_error_dumps_postmortem(tmp_path):
     for."""
     plan = _plan()
     bad, _ = CORRUPTIONS["send_conflict"][0](plan)
-    tr = Tracer(sync=False)
+    tr = Tracer()
     lg = EventLog(level="debug")
     cache = SymbolicCache(tracer=tr, event_log=lg)
     pm_path = str(tmp_path / "postmortem.json")
@@ -226,7 +226,7 @@ def test_memory_meter_peaks_and_flush():
     assert np.array_equal(mm.peak["norm_table"], [100.0, 300.0, 200.0, 100.0])
     mm.note_bytes("recv", np.full(4, 10.0))
     assert np.array_equal(mm.worker_peak(), [110.0, 310.0, 210.0, 110.0])
-    tr = Tracer(sync=False)
+    tr = Tracer()
     mm.flush(tr)
     assert tr.gauge("mem_peak_w1_bytes").value == pytest.approx(310.0)
     summary = mm.summary()
@@ -321,7 +321,7 @@ def test_convergence_stall_detector():
 def test_alerts_land_in_log_and_trace():
     from repro.obs.health import HealthMonitor, HealthPolicy
 
-    tr = Tracer(sync=False)
+    tr = Tracer()
     cache = SymbolicCache(tracer=tr, event_log=EventLog())
     hm = HealthMonitor(HealthPolicy(stall_window=2), cache=cache)
     for it in range(5):
@@ -563,7 +563,7 @@ skew = np.zeros(F.nnzb, dtype=np.int32)
 dFs = scatter(F, mesh, owner=skew)
 d0, st0 = dist_sp2_purify(dFs, nocc, lmin, lmax, cache=PlanCache(),
                           rebalance=RebalancePolicy(), **kw)
-cache = PlanCache(tracer=Tracer(sync=False),
+cache = PlanCache(tracer=Tracer(),
                   event_log=EventLog(os.path.join(tmp, "ev.jsonl"),
                                      level="debug"))
 mm = MemoryMeter().install(cache)
@@ -614,7 +614,7 @@ class DivergeNow(inv.RefineMonitor):
         return False
 real_mon = inv.RefineMonitor
 inv.RefineMonitor = DivergeNow
-cache3 = PlanCache(tracer=Tracer(sync=False),
+cache3 = PlanCache(tracer=Tracer(),
                    event_log=EventLog(level="debug"))
 pm_path = os.path.join(tmp, "pm_refine.json")
 rec3 = FlightRecorder(pm_path, last_spans=32).install(cache3)

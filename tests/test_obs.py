@@ -48,7 +48,7 @@ class Tick:
 # -- tracer core --------------------------------------------------------------
 
 def test_span_nesting_and_durations():
-    tr = Tracer(clock=Tick(), sync=False)
+    tr = Tracer(clock=Tick())
     with tr.span("phase", cat="phase"):
         with tr.span("inner") as sp:
             sp.args.update(k=1)
@@ -66,7 +66,7 @@ def test_span_nesting_and_durations():
 
 
 def test_counters_and_gauges_register_once():
-    tr = Tracer(sync=False)
+    tr = Tracer()
     c = tr.counter("bytes")
     c.add(3)
     tr.counter("bytes").add(4)  # same object
@@ -87,14 +87,13 @@ def test_null_tracer_is_inert():
     NULL_TRACER.gauge("g").set(5)
     NULL_TRACER.instant("i")
     assert NULL_TRACER.metrics_flat() == {}
-    assert NULL_TRACER.sync("v") == "v"
 
 
 def test_tracer_of_rides_on_the_cache():
     assert tracer_of(None) is NULL_TRACER
     c = SymbolicCache()
     assert tracer_of(c) is NULL_TRACER
-    tr = Tracer(sync=False)
+    tr = Tracer()
     c.tracer = tr
     assert tracer_of(c) is tr
     c.tracer = None  # assigning None disables tracing (normalized)
@@ -102,11 +101,121 @@ def test_tracer_of_rides_on_the_cache():
     assert SymbolicCache(tracer=tr).tracer is tr
 
 
+def test_compiles_count_only_under_spans_until_close():
+    import jax
+
+    tr = Tracer()
+    jax.jit(lambda x: x * 3.0 + 1.0)(np.ones(3))  # no span open
+    assert tr.metrics_flat().get("compiles", 0) == 0
+    with tr.span("step", kind="k"):
+        jax.jit(lambda x: x * 5.0 - 2.0)(np.ones(5))
+    (ev,) = tr.instants_of("compile")
+    assert ev["span"] == "step" and ev["kind"] == "k" and ev["secs"] > 0
+    assert tr.counter("compiles").value == 1
+    assert tr.counter("compile_s").value == ev["secs"]
+    tr.close()
+    tr.close()  # idempotent
+    with tr.span("step"):
+        jax.jit(lambda x: x * 7.0 - 4.0)(np.ones(7))
+    assert tr.counter("compiles").value == 1
+
+
+# -- library spans in the profiler trace, compiles, host fetches --------------
+
+@pytest.fixture(scope="module")
+def traced_multiply(tmp_path_factory):
+    """Two ``dist_multiply`` calls on one structure with a tracer, under a
+    CPU profiler capture; the tracer, the compile instants after the first
+    call, and the capture's ``repro.*`` host events."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core import BSMatrix
+    from repro.core.distributed import make_worker_mesh
+    from repro.dist import PlanCache, dist_multiply, scatter
+
+    rng = np.random.default_rng(11)
+    n, bs = 44, 4  # shapes no other test here compiles
+    band = np.abs(np.subtract.outer(np.arange(n), np.arange(n))) < 9
+    a = scatter(BSMatrix.from_dense(
+        (rng.standard_normal((n, n)) * band).astype(np.float32), bs),
+        make_worker_mesh(1))
+    tr = Tracer()
+    cache = PlanCache(tracer=tr)
+    logdir = str(tmp_path_factory.mktemp("profile"))
+    with jax.profiler.trace(logdir):
+        dist_multiply(a, a, cache, impl="ref").store.block_until_ready()
+        first = list(tr.instants_of("compile"))
+        dist_multiply(a, a, cache, impl="ref").store.block_until_ready()
+    tr.close()
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(logdir)
+               for f in fs if f.endswith(".xplane.pb")]
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("repro.")]
+    return tr, first, events
+
+
+def test_spans_reach_the_profiler_trace_nested(traced_multiply):
+    tr, _, events = traced_multiply
+    by_name: dict = {}
+    for name, s, e in events:
+        by_name.setdefault(name, []).append((s, e))
+    assert len(by_name["repro.dist_multiply"]) == 2
+    assert len(by_name["repro.plan_key"]) == 2
+    assert len(by_name["repro.dispatch"]) == 2
+    for child in ("repro.plan_key", "repro.dispatch"):
+        for s, e in by_name[child]:
+            assert any(ps <= s and e <= pe
+                       for ps, pe in by_name["repro.dist_multiply"])
+    # the miss's planning lands inside the first call only
+    assert len(by_name["repro.plan_build"]) == 1
+    # the tracer keeps the same spans in memory
+    assert [s.name for s in tr.spans].count("dispatch") == 2
+
+
+def test_first_dispatch_records_one_compile(traced_multiply):
+    tr, first, _ = traced_multiply
+    under_dispatch = [ev for ev in first if ev["span"] == "dispatch"]
+    assert len(under_dispatch) == 1
+    assert under_dispatch[0]["kind"] == "spgemm"
+    # the repeat call hits the plan cache and compiles nothing
+    assert tr.instants_of("compile") == first
+    assert tr.counter("compiles").value == len(first)
+
+
+def test_sp2_iteration_counts_three_host_fetches():
+    from repro.core import BSMatrix
+    from repro.core.distributed import make_worker_mesh
+    from repro.dist import PlanCache, dist_sp2_purify, scatter
+
+    rng = np.random.default_rng(5)
+    n, bs = 40, 8
+    h = rng.standard_normal((n, n)) * (
+        np.abs(np.subtract.outer(np.arange(n), np.arange(n))) < 12)
+    f = ((h + h.T) / 2).astype(np.float32)
+    w = np.linalg.eigvalsh(f.astype(np.float64))
+    tr = Tracer()
+    _, st = dist_sp2_purify(
+        scatter(BSMatrix.from_dense(f, bs), make_worker_mesh(1)), n // 2,
+        float(w.min()) - 0.1, float(w.max()) + 0.1, cache=PlanCache(),
+        tracer=tr, max_iter=1, trunc_tau=1e-3, impl="ref")
+    tr.close()
+    assert len(st.per_iter) == 1
+    it = next(s for s in tr.spans if s.name == "sp2_iteration")
+    fetches = [v for t, name, v in tr._counter_events
+               if name == "host_fetches" and it.t0 <= t <= it.t1]
+    # ||X² - X||_F, trace(X), and the norm table for truncation
+    assert fetches == [1.0, 2.0, 3.0]
+
+
 # -- shared timing idioms -----------------------------------------------------
 
 def test_timed_into_accumulates_and_emits_span():
     cache = SymbolicCache()
-    tr = Tracer(clock=Tick(), sync=False)
+    tr = Tracer(clock=Tick())
     with timed_into(cache, "symbolic_s", tr, "descent", cat="symbolic", n=3):
         pass
     assert cache.symbolic_s > 0
@@ -125,7 +234,7 @@ def test_timed_into_accumulates_and_emits_span():
 
 def test_iteration_scope_row_schema():
     cache = SymbolicCache()
-    tr = Tracer(clock=Tick(), sync=False)
+    tr = Tracer(clock=Tick())
     with IterationScope(cache, 2, tr, name="sp2_iteration") as scope:
         cache.get_or_build(("k",), lambda: 1)
         row = scope.row(nnzb=7, idem=0.5)
@@ -140,7 +249,7 @@ def test_iteration_scope_row_schema():
 
 
 def test_cache_plan_counters_flow_to_tracer():
-    tr = Tracer(sync=False)
+    tr = Tracer()
     cache = SymbolicCache(tracer=tr)
     cache.get_or_build(("spgemm", 1), lambda: "v")
     cache.get_or_build(("spgemm", 1), lambda: "v")
@@ -157,7 +266,7 @@ def test_cache_plan_counters_flow_to_tracer():
 # -- export + report ----------------------------------------------------------
 
 def _synthetic_tracer():
-    tr = Tracer(clock=Tick(), sync=False)
+    tr = Tracer(clock=Tick())
     with tr.span("phase", cat="phase"):
         with tr.span("dispatch", cat="dispatch") as sp:
             sp.worker_costs = np.array([2.0, 1.0, 0.0, 1.0])
@@ -212,7 +321,7 @@ def test_worker_utilization_math(tmp_path):
 
 
 def test_attributed_busy_never_nests():
-    tr = Tracer(clock=Tick(), sync=False)
+    tr = Tracer(clock=Tick())
     with tr.span("outer", cat="collective") as outer:
         outer.worker_costs = np.array([1.0, 1.0])
         with tr.span("inner", cat="dispatch") as inner:
