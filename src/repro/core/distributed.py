@@ -149,40 +149,35 @@ def _fused_spmm_fn(impl: str):
     return kops.fused_block_spmm
 
 
-def fused_task_layout(
-    task_c: np.ndarray, task_count: np.ndarray, c_cap: int, max_tasks: int
+def _run_starts(c: np.ndarray) -> np.ndarray:
+    """Positions where a new run of equal ``c`` (one output row) begins."""
+    if not c.size:
+        return np.zeros(0, np.int64)
+    return np.concatenate([[0], np.nonzero(np.diff(c))[0] + 1])
+
+
+def _chunk_slots(
+    cs: list[np.ndarray], max_tasks: int, lead_empty: bool = False
 ) -> tuple[np.ndarray, np.ndarray, tuple[int, ...]]:
-    """Lay each device's task list out in kernel-call chunks.
-
-    One SMEM-bounded kernel call carries at most ``max_tasks`` tasks, and
-    every device of an SPMD program runs the same calls, so each device's
-    ``[task_count]`` list is cut at ``c``-run boundaries into the same number
-    ``K`` of chunks, chunk ``k`` padded to the common length ``L_k``.
-    Returns ``(idx, real, lengths)``: ``idx [P, sum(L)]`` is the device task
-    each slot repeats, ``real`` marks slots that are that task and not
-    padding, ``lengths`` is ``(L_0, ..., L_{K-1})``.
-
-    Padding contributes nothing (its ``on`` flag is clear) and never
-    changes the output row: it repeats its chunk's last task, so that row
-    is neither re-zeroed nor left.  A device cut into fewer than ``K``
-    chunks has fewer runs than ``K``, hence fewer valid rows than the
-    device with the most chunks — so ``c_cap - 1`` is a padding row there,
-    and its empty chunks (``idx == -1``) point at it.
-    """
-    nparts = task_c.shape[0]
-    runs, cuts = [], []
-    for p in range(nparts):
-        c = task_c[p, : int(task_count[p])]
-        runs.append(
-            np.concatenate([[0], np.nonzero(np.diff(c))[0] + 1]) if c.size
-            else np.zeros(0, np.int64))
-        cuts.append(len(task_chunks(c, max_tasks)) if c.size else 0)
+    """Cut each device's ``c`` sequence into the same number ``K`` of
+    kernel-call chunks at run boundaries, chunk ``k`` padded to the common
+    length ``L_k``.  Returns ``(pos, real, lengths)``: ``pos [P, sum(L)]``
+    is the position in ``cs[p]`` each slot repeats (``-1`` in an empty
+    chunk), ``real`` marks slots that are that position and not padding.
+    A device with fewer runs than ``K`` gets one run per chunk and its empty
+    chunks last, or first with ``lead_empty``."""
+    nparts = len(cs)
+    runs = [_run_starts(c) for c in cs]
+    cuts = [len(task_chunks(c, max_tasks)) if c.size else 0 for c in cs]
     K = max(max(cuts), 1)
     bounds = []
     for p in range(nparts):
-        n, st = int(task_count[p]), runs[p]
-        if st.size < K:  # one run per chunk, then empty chunks
-            b = list(st) + [n] * (K + 1 - st.size)
+        n, st = cs[p].size, runs[p]
+        if st.size < K:  # one run per chunk, and empty chunks
+            if lead_empty:
+                b = [0] * (K - st.size) + list(st) + [n]
+            else:
+                b = list(st) + [n] * (K + 1 - st.size)
         else:
             # greedy cuts, held back so every later chunk keeps >= one run
             b, cur = [0], 0
@@ -197,24 +192,175 @@ def fused_task_layout(
     spans = np.stack([np.diff(b) for b in bounds])  # [P, K]
     lengths = tuple(int(x) for x in np.maximum(spans.max(axis=0), 1))
     assert max(lengths) <= max_tasks, lengths
-    idx = np.full((nparts, sum(lengths)), -1, np.int64)
-    real = np.zeros(idx.shape, bool)
+    pos = np.full((nparts, sum(lengths)), -1, np.int64)
+    real = np.zeros(pos.shape, bool)
     off = 0
     for k, L in enumerate(lengths):
         for p in range(nparts):
             lo, n = int(bounds[p][k]), int(spans[p, k])
             if n:
-                idx[p, off : off + n] = np.arange(lo, lo + n)
-                idx[p, off + n : off + L] = lo + n - 1
+                pos[p, off : off + n] = np.arange(lo, lo + n)
+                pos[p, off + n : off + L] = lo + n - 1
                 real[p, off : off + n] = True
         off += L
-    return idx, real, lengths
+    return pos, real, lengths
+
+
+def _local_first_split(task_c, task_count, local):
+    """Per-device ``(phase-1 tasks, phase-2 tasks)`` of the two-phase
+    layout, or ``None`` when it does not apply.
+
+    Phase 1 takes C rows whose every task reads only owned blocks, in
+    ascending order, as many whole runs as fit in ``L1`` tasks: the
+    smallest device's all-local task count, so every device fills about
+    the same ``L1`` slots.  The rest (remote rows, and a device's surplus
+    local rows) is phase 2, which keeps at least one run on every device.
+    No split when no task reads a received block (no exchange to hide) or
+    some device has no all-local row (``L1 == 0``).
+    """
+    nparts = task_c.shape[0]
+    rows = []
+    for p in range(nparts):
+        n = int(task_count[p])
+        st = _run_starts(task_c[p, :n])
+        ln, loc = np.diff(np.append(st, n)), local[p, :n]
+        row_local = np.logical_and.reduceat(loc, st) if n else np.zeros(0, bool)
+        rows.append((ln, row_local))
+    if all(ln[~rl].size == 0 for ln, rl in rows):
+        return None
+    L1 = min(int(ln[rl].sum()) for ln, rl in rows)
+    if L1 == 0:
+        return None
+    split = []
+    for ln, rl in rows:
+        # whole local runs, ascending, while the prefix fits in L1
+        take = rl & (np.cumsum(np.where(rl, ln, 0)) <= L1)
+        if take.all():  # phase 2 needs a run on every device
+            take[-1] = False
+        first = np.repeat(take, ln)
+        split.append((np.nonzero(first)[0], np.nonzero(~first)[0]))
+    return split
+
+
+def fused_task_layout(
+    task_c: np.ndarray,
+    task_count: np.ndarray,
+    c_cap: int,
+    max_tasks: int,
+    local: np.ndarray | None = None,
+) -> tuple[np.ndarray, np.ndarray, tuple[int, ...], int]:
+    """Lay each device's task list out in kernel-call chunks.
+
+    One SMEM-bounded kernel call carries at most ``max_tasks`` tasks, and
+    every device of an SPMD program runs the same calls, so each device's
+    ``[task_count]`` list is cut at ``c``-run boundaries into the same number
+    ``K`` of chunks, chunk ``k`` padded to the common length ``L_k``.
+    Returns ``(idx, real, lengths, n_local)``: ``idx [P, sum(L)]`` is the
+    device task each slot repeats, ``real`` marks slots that are that task
+    and not padding, ``lengths`` is ``(L_0, ..., L_{K-1})``, and the first
+    ``n_local`` chunks are phase 1 (below).
+
+    Padding contributes nothing (its ``on`` flag is clear) and never
+    changes the output row: it repeats its chunk's last task, so that row
+    is neither re-zeroed nor left.  A device cut into fewer than ``K``
+    chunks has fewer runs than ``K``, hence fewer valid rows than the
+    device with the most chunks — so ``c_cap - 1`` is a padding row there,
+    and its empty chunks (``idx == -1``) point at it.
+
+    Two phases, from ``local`` (``[P, t_cap]``: the task reads only owned
+    blocks): phase 1 holds C rows whose every task is local, so its calls
+    need no received block and can run while the exchange is in flight;
+    phase 2 holds the rest (:func:`_local_first_split`).  Each C row stays
+    in one call, in its original task order, so C is bit-identical; an
+    empty chunk points at the device's next laid-out row, which a later
+    call zeroes again.  With no split ``n_local`` is 0 and the layout is the
+    single-phase one above.
+    """
+    nparts = task_c.shape[0]
+    split = None if local is None else _local_first_split(
+        task_c, task_count, local)
+    if split is None:
+        cs = [task_c[p, : int(task_count[p])] for p in range(nparts)]
+        idx, real, lengths = _chunk_slots(cs, max_tasks)
+        return idx, real, lengths, 0
+    idx, real, phases = [], [], []
+    for phase in range(2):
+        seqs = [s[phase] for s in split]
+        pos, r, ln = _chunk_slots(
+            [task_c[p, seqs[p]] for p in range(nparts)], max_tasks,
+            lead_empty=True)
+        # position -1 (an empty chunk) picks the appended -1
+        idx.append(np.stack([np.append(seqs[p], -1)[pos[p]] for p in range(nparts)]))
+        real.append(r)
+        phases.append(ln)
+    lengths, n_local = phases[0] + phases[1], len(phases[0])
+    idx, real = np.concatenate(idx, axis=1), np.concatenate(real, axis=1)
+    n1 = sum(lengths[:n_local])
+    for p in range(nparts):
+        # every device ends on a phase-2 run, so each empty chunk has a
+        # later laid-out row to point at; phase 2 zeroes that row again
+        laid = np.nonzero(idx[p] >= 0)[0]
+        assert laid.size and real[p, n1:].any(), p
+        idx[p] = idx[p, laid[np.searchsorted(laid, np.arange(idx.shape[1]))]]
+        first, rest = idx[p, :n1][real[p, :n1]], idx[p, n1:][real[p, n1:]]
+        # phase 1 reads only owned blocks, and no row is in both phases
+        assert local[p, first].all(), p
+        assert not np.intersect1d(task_c[p, first], task_c[p, rest]).size, p
+    return idx, real, lengths, n_local
 
 
 def _take_layout(x: np.ndarray, idx: np.ndarray, fill) -> np.ndarray:
     """``x[p, idx[p]]`` per device, ``fill`` where ``idx == -1``."""
     got = np.take_along_axis(x, np.maximum(idx, 0), axis=1)
     return np.where(idx >= 0, got, fill).astype(x.dtype)
+
+
+def fused_task_arrays(
+    plan: SpgemmPlan, idx: np.ndarray, n_before: int
+) -> list[np.ndarray]:
+    """The laid-out ``[a_src, a_off, b_src, b_off, c]`` task arrays of a
+    :func:`fused_task_layout`, whose first ``n_before`` slots are phase 1.
+
+    An empty phase-1 chunk repeats a phase-2 row, which may be remote; its
+    slots read the own store's row 0 instead, as phase 1 has no receive
+    buffer.  So every phase-1 slot reads ``src == 0``.
+    """
+    lay = lambda x, fill: _take_layout(x, idx, fill)
+    arrays = [
+        lay(plan.task_a_src, 0),
+        lay(plan.task_a_off, 0),
+        lay(plan.task_b_src, 0),
+        lay(plan.task_b_off, 0),
+        lay(plan.task_c, plan.c_cap - 1),
+    ]
+    remote = (arrays[0][:, :n_before] != 0) | (arrays[2][:, :n_before] != 0)
+    for x in arrays[:4]:
+        x[:, :n_before][remote] = 0
+    return arrays
+
+
+def _fused_phases(
+    spmm, a_own, a_recv, b_own, b_recv, tasks, num_out, phases, *,
+    on, low=None, adaptive=False,
+):
+    """Run the fused engine over the layout's phases, threading C through
+    ``acc``.  With two phases the first reads only the own stores: its calls
+    get dummy receive stacks, so none of its operands waits on a ppermute
+    and the exchange can be in flight while it runs."""
+    c, s = None, 0
+    for k, chunks in enumerate(phases):
+        n = sum(chunks)
+        sl = lambda x: None if x is None else x[s : s + n]
+        ar, br = a_recv, b_recv
+        if k + 1 < len(phases):
+            ar = jnp.zeros((1, 1) + a_own.shape[-2:], a_own.dtype)
+            br = jnp.zeros((1, 1) + b_own.shape[-2:], b_own.dtype)
+        c = spmm(
+            a_own, ar, b_own, br, *map(sl, tasks), num_out,
+            on=sl(on), low=sl(low), adaptive=adaptive, chunks=chunks, acc=c,
+        )
+        s += n
+    return c
 
 
 def _mapped_multiply_fused(
@@ -230,7 +376,7 @@ def _mapped_multiply_fused(
     plan: SpgemmPlan,
     impl: str,
     precision: Precision,
-    chunks: tuple[int, ...],
+    phases: tuple[tuple[int, ...], ...],
 ):
     """Fused per-device body: exchange -> fused unpack+GEMM+accumulate
     dispatches over (own store | stacked receive buffers), writing the
@@ -245,10 +391,10 @@ def _mapped_multiply_fused(
         b_own = b_own.astype(jnp.bfloat16)
     a_recv = _exchange_stack(a_own, plan.a_offsets, a_sends, plan.nparts)
     b_recv = _exchange_stack(b_own, plan.b_offsets, b_sends, plan.nparts)
-    c = _fused_spmm_fn(impl)(
-        a_own, a_recv, b_own, b_recv,
-        a_src_t[0], a_off_t[0], b_src_t[0], b_off_t[0], task_c[0],
-        plan.c_cap, on=task_on[0], chunks=chunks,
+    tasks = (a_src_t[0], a_off_t[0], b_src_t[0], b_off_t[0], task_c[0])
+    c = _fused_phases(
+        _fused_spmm_fn(impl), a_own, a_recv, b_own, b_recv, tasks,
+        plan.c_cap, phases, on=task_on[0],
     )
     return c[None]
 
@@ -267,7 +413,7 @@ def _mapped_multiply_fused_masked(
     plan: SpgemmPlan,
     impl: str,
     precision: Precision,
-    chunks: tuple[int, ...],
+    phases: tuple[tuple[int, ...], ...],
     live_a: tuple[int, ...],
     live_b: tuple[int, ...],
 ):
@@ -290,12 +436,11 @@ def _mapped_multiply_fused_masked(
         b_own, plan.b_offsets, b_sends, plan.nparts, keeps=b_keeps, live=live_b
     )
     adaptive = precision.mode == "adaptive"
-    c = _fused_spmm_fn(impl)(
-        a_own, a_recv, b_own, b_recv,
-        a_src_t[0], a_off_t[0], b_src_t[0], b_off_t[0], task_c[0],
-        plan.c_cap, on=task_on[0],
-        low=task_low[0] if adaptive else None,
-        adaptive=adaptive, chunks=chunks,
+    tasks = (a_src_t[0], a_off_t[0], b_src_t[0], b_off_t[0], task_c[0])
+    c = _fused_phases(
+        _fused_spmm_fn(impl), a_own, a_recv, b_own, b_recv, tasks,
+        plan.c_cap, phases, on=task_on[0],
+        low=task_low[0] if adaptive else None, adaptive=adaptive,
     )
     return c[None]
 
@@ -488,25 +633,34 @@ class FusedSpgemmExecutable(SpgemmExecutable):
             "adaptive precision needs the masked fused executable"
         )
         self.precision = precision
-        self._idx, self._real, self.chunks = fused_task_layout(
-            plan.task_c, plan.task_count, plan.c_cap, self._max_tasks
+        local = (plan.task_a_src == 0) & (plan.task_b_src == 0)
+        self._idx, self._real, self.chunks, n_local = fused_task_layout(
+            plan.task_c, plan.task_count, plan.c_cap, self._max_tasks, local
         )
+        # kernel calls per phase: local-first, then the rest (one phase
+        # when the layout has no split)
+        self.phases = (
+            (self.chunks[:n_local], self.chunks[n_local:]) if n_local
+            else (self.chunks,)
+        )
+        self._n_before = sum(self.chunks[:n_local])
         super().__init__(
-            plan, mesh, impl=impl, precision=precision, chunks=self.chunks,
+            plan, mesh, impl=impl, precision=precision, phases=self.phases,
             **body_kwargs,
         )
 
     def _plan_index_arrays(self, plan: SpgemmPlan) -> list[np.ndarray]:
-        lay = lambda x, fill: _take_layout(x, self._idx, fill)
-        arrays = [
-            lay(plan.task_a_src, 0),
-            lay(plan.task_a_off, 0),
-            lay(plan.task_b_src, 0),
-            lay(plan.task_b_off, 0),
-            lay(plan.task_c, plan.c_cap - 1),
-        ]
+        arrays = fused_task_arrays(plan, self._idx, self._n_before)
         # the unmasked body's on flags are fixed: real tasks only
         return arrays if self._masked else arrays + [self._real]
+
+    def tasks_before_exchange(self, task_on: np.ndarray | None = None) -> int:
+        """Real tasks (``task_on`` ones, when given) in phase 1: the work
+        laid out to run before any received block is needed."""
+        head = self._real[:, : self._n_before]
+        if task_on is not None:
+            head = head & self._task_table(task_on)[:, : self._n_before]
+        return int(head.sum())
 
     def _task_table(self, x: np.ndarray) -> np.ndarray:
         """A per-call ``[P, t_cap]`` task table in the chunked layout, with

@@ -165,12 +165,13 @@ def _plan_obs_static(plan) -> dict:
 
 
 def _annotate_spgemm_dispatch(
-    tr, sp, plan, task_count, precision: Precision | None = None, exe=None
+    tr, sp, plan, task_count, precision: Precision | None = None, exe=None,
+    task_on=None,
 ) -> None:
     """Per-worker attribution + byte/task counters on an executed multiply
     dispatch span.  Callers guard on ``tr.enabled`` — this does real work
     (plan byte accounting, cost-model evaluation) that must cost nothing
-    with tracing off.
+    with tracing off.  ``task_on`` is a masked dispatch's task mask.
     """
     st = _plan_obs_static(plan)
     if precision is not None:
@@ -204,6 +205,12 @@ def _annotate_spgemm_dispatch(
     sp.args.update(tasks=tasks, recv_bytes=st["recv_sum"],
                    send_bytes=st["send_sum"])
     tr.counter("tasks_executed").add(float(tasks))
+    before = getattr(exe, "tasks_before_exchange", None)
+    if before is not None:
+        # fused layouts: tasks laid out ahead of the first received block
+        n = before(task_on)
+        sp.args.update(tasks_before_exchange=n)
+        tr.counter("tasks_before_exchange").add(float(n))
     tr.counter("recv_bytes").add(st["recv_sum"])
     tr.counter("send_bytes").add(st["send_sum"])
     # exchange rounds run fused inside the jitted dispatch — emit honest
@@ -667,7 +674,7 @@ def _dist_spamm_impl(
                 c_store = exe(a.store, b.store, task_on)
         if tr.enabled:
             _annotate_spgemm_dispatch(
-                tr, sp, plan, masked_count, precision, exe
+                tr, sp, plan, masked_count, precision, exe, task_on
             )
         c = DistBSMatrix(
             shape=(a.shape[0], b.shape[1]),

@@ -106,10 +106,11 @@ def _chunks_of(c_idx, chunks, max_tasks: int) -> tuple[int, ...]:
     return task_chunks(c_idx, max_tasks)
 
 
-def _run_chunks(call, per_task, chunks):
+def _run_chunks(call, per_task, chunks, acc=None):
     """Run ``call(*slices, acc)`` over consecutive chunks of the per-task
-    arrays, threading the accumulated output through."""
-    out, s = None, 0
+    arrays, threading the accumulated output through (from ``acc``, if
+    given)."""
+    out, s = acc, 0
     for n in chunks:
         out = call(*(x[s : s + n] for x in per_task), out)
         s += n
@@ -174,6 +175,7 @@ def fused_block_spmm(
     adaptive: bool = False,
     interpret: bool | None = None,
     chunks: tuple[int, ...] | None = None,
+    acc: jax.Array | None = None,
     _max_tasks: int = FUSED_MAX_TASKS,
 ) -> jax.Array:
     """Fused unpack + grouped block matmul + accumulate (the leaf engine).
@@ -194,11 +196,13 @@ def fused_block_spmm(
     kernel cannot take run the reference; on the TPU they raise.  A task
     list longer than one call's SMEM bound runs as several calls split at
     ``c``-run boundaries (``chunks``, or :func:`task_chunks` of a concrete
-    ``c_idx``).  Returns fp32 ``[num_out, bm, bn]``.
+    ``c_idx``).  ``acc`` continues an earlier call's output: rows this
+    list does not visit keep their values.  Returns fp32
+    ``[num_out, bm, bn]``.
     """
     bm, bk, bn = a_store.shape[1], a_store.shape[2], b_store.shape[2]
     if a_src.shape[0] == 0:
-        return jnp.zeros((num_out, bm, bn), jnp.float32)
+        return jnp.zeros((num_out, bm, bn), jnp.float32) if acc is None else acc
     i32 = lambda x: jnp.asarray(x, jnp.int32)
     flags = jnp.full(a_src.shape, FLAG_ON, jnp.int32)
     if on is not None:
@@ -227,7 +231,7 @@ def fused_block_spmm(
                 a_store, a_recv, b_store, b_recv, *xs,
                 num_out=num_out, adaptive=adaptive, interpret=bool(interpret),
             )
-    return _run_chunks(call, per_task, chunks)
+    return _run_chunks(call, per_task, chunks, acc)
 
 
 def grouped_gemm_varsize(

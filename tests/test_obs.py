@@ -477,6 +477,29 @@ futil = utilization_from_file(path)
 out["util_file_close"] = bool(abs(
     futil["timeline_imbalance"] - util["timeline_imbalance"]) < 1e-6)
 
+# -- tasks laid out ahead of the exchange: counted with a tracer on ----------
+from repro.core.distributed import FusedSpgemmExecutable
+from repro.core.families import band_block_coords
+from repro.core.schedule import make_spgemm_plan
+from repro.dist import dist_multiply
+coords = band_block_coords(24, 3)
+blocks = rng.standard_normal((len(coords), bs, bs)).astype(np.float32)
+dA = scatter(BSMatrix(shape=(24 * bs, 24 * bs), bs=bs, coords=coords,
+                      data=blocks), mesh)
+plan = make_spgemm_plan(coords, coords, 4, bs, exchange="p2p",
+                        a_owner=dA.owner, b_owner=dA.owner)
+exe = FusedSpgemmExecutable(plan, mesh)
+n1 = sum(exe.phases[0]) if len(exe.phases) == 2 else 0
+tr3 = Tracer()
+dist_multiply(dA, dA, PlanCache(tracer=tr3))
+cache_off = PlanCache()
+dist_multiply(dA, dA, cache_off)
+out["before_exchange"] = dict(
+    counted=tr3.counter("tasks_before_exchange").value,
+    phase1_real=int(exe._real[:, :n1].sum()),
+    executed=tr3.counter("tasks_executed").value,
+    off_keys=sorted(k for k in run_metrics(cache_off) if "exchange" in k))
+
 print("RESULT " + json.dumps(out))
 """
 
@@ -539,3 +562,10 @@ def test_exported_trace_one_track_per_worker(obs_results):
     assert obs_results["util_nparts"] == 4
     assert obs_results["util_fracs_sane"]
     assert obs_results["util_file_close"]
+
+
+def test_tasks_before_exchange_counter(obs_results):
+    r = obs_results["before_exchange"]
+    assert r["phase1_real"] > 0  # the banded plan splits over four devices
+    assert r["counted"] == r["phase1_real"] < r["executed"]
+    assert r["off_keys"] == []  # nothing is counted with the tracer off

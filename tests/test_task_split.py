@@ -154,8 +154,9 @@ def test_fused_task_layout_multi_device():
     for p, x in enumerate(lists):
         task_c[p, : x.size] = x
     count = np.array([x.size for x in lists])
-    idx, real, lengths = fused_task_layout(task_c, count, c_cap, 5)
+    idx, real, lengths, n_local = fused_task_layout(task_c, count, c_cap, 5)
     K = len(lengths)
+    assert n_local == 0  # no locality given: one phase
     assert K == len(task_chunks(C, 5)) and max(lengths) <= 5
     offs = np.cumsum((0,) + lengths)
     for p, x in enumerate(lists):
@@ -184,7 +185,7 @@ def test_fused_task_layout_padding_contributes_nothing():
     for p, x in enumerate(lists):
         task_c[p, : x.size] = x
     count = np.array([x.size for x in lists])
-    idx, real, lengths = fused_task_layout(task_c, count, c_cap, 5)
+    idx, real, lengths, _ = fused_task_layout(task_c, count, c_cap, 5)
     for p, x in enumerate(lists):
         q = _problem(x, seed=p)
         want = np.zeros((c_cap, 16, 16), np.float32)
@@ -234,3 +235,187 @@ def test_executable_split_bit_identical():
     want = np.asarray(
         MaskedFusedSpgemmExecutable(plan, mesh, prune_exchange=False)(store, store, on))
     np.testing.assert_array_equal(got, want)
+
+
+# -- two-phase layout: rows that need no received block first ---------------
+
+def _plan(structure: str, nparts: int):
+    from repro.core.families import band_block_coords, morton_sort, random_block_starts
+    from repro.core.schedule import make_spgemm_plan
+
+    coords = band_block_coords(24, 3)
+    if structure == "random":
+        # the band plus one dense diagonal block at a seeded position
+        coords = band_block_coords(24, 2)
+        have = {tuple(x) for x in coords.tolist()}
+        (b0,) = random_block_starts(24, 6, 1, seed=0)
+        extra = [(i, j) for i in range(b0, b0 + 6) for j in range(b0, b0 + 6)
+                 if (i, j) not in have]
+        coords = np.concatenate([coords, np.array(extra, np.int64)])
+        coords = coords[morton_sort(coords)]
+    return make_spgemm_plan(coords, coords, nparts, 8, exchange="p2p")
+
+
+def _local(plan):
+    return (plan.task_a_src == 0) & (plan.task_b_src == 0)
+
+
+def _accumulation_ok(c, real, lengths):
+    """Replay the kernel's zeroing on one device's laid-out rows: a row is
+    zeroed at each call's first slot and where ``c`` changes.  Every row a
+    real task feeds must be zeroed at or before its first contribution and
+    never after it, and every call's rows ascend."""
+    zeros, feeds = {}, {}
+    offs = np.cumsum((0,) + tuple(lengths))
+    for lo, hi in zip(offs[:-1], offs[1:]):
+        seg = c[lo:hi]
+        if (np.diff(seg) < 0).any():
+            return False
+        for s in range(lo, hi):
+            if s == lo or c[s] != c[s - 1]:
+                zeros.setdefault(int(c[s]), []).append(s)
+            if real[s]:
+                feeds.setdefault(int(c[s]), []).append(s)
+    # a slot zeroes its row before it adds to it
+    return all(r in zeros and max(zeros[r]) <= min(f) for r, f in feeds.items())
+
+
+@pytest.mark.parametrize("max_tasks", [FUSED_MAX_TASKS, 40])
+@pytest.mark.parametrize("structure", ["banded", "random"])
+def test_two_phase_layout(structure, max_tasks):
+    from repro.core.distributed import fused_task_arrays
+
+    plan = _plan(structure, 4)
+    idx, real, lengths, n_local = fused_task_layout(
+        plan.task_c, plan.task_count, plan.c_cap, max_tasks, _local(plan))
+    _, _, today, _ = fused_task_layout(
+        plan.task_c, plan.task_count, plan.c_cap, max_tasks)
+    assert 1 <= n_local < len(lengths)
+    n1 = sum(lengths[:n_local])
+    a_src, _, b_src, _, c = fused_task_arrays(plan, idx, n1)
+    # every phase-1 slot, padding included, reads the own store alone
+    assert (a_src[:, :n1] == 0).all() and (b_src[:, :n1] == 0).all()
+    offs = np.cumsum((0,) + lengths)
+    for p in range(plan.nparts):
+        n = int(plan.task_count[p])
+        order = idx[p][real[p]]
+        assert sorted(order.tolist()) == list(range(n))  # each task once
+        calls = [set(c[p, lo:hi][real[p, lo:hi]].tolist())
+                 for lo, hi in zip(offs[:-1], offs[1:])]
+        # no row in two calls, and each row keeps its tasks in plan order
+        assert sum(map(len, calls)) == len(set().union(*calls))
+        for row in set().union(*calls):
+            mine = order[plan.task_c[p, order] == row]
+            assert (np.diff(mine) == 1).all()
+        assert _accumulation_ok(c[p], real[p], lengths)
+    if max_tasks == FUSED_MAX_TASKS:
+        # one run of padding more than today's single call, at most
+        c_runs = [np.diff(np.nonzero(np.diff(
+            np.r_[-1, plan.task_c[p, : plan.task_count[p]], -2]))[0])
+            for p in range(plan.nparts)]
+        assert sum(lengths) <= today[0] + max(r.max() for r in c_runs)
+
+
+@pytest.mark.parametrize("structure", ["banded", "random"])
+def test_two_phase_layout_one_device_is_today(structure):
+    # no exchange rounds: every task is local and the layout is unchanged
+    plan = _plan(structure, 1)
+    assert not plan.a_offsets and _local(plan)[0, : plan.task_count[0]].all()
+    for max_tasks in (FUSED_MAX_TASKS, 40):
+        got = fused_task_layout(
+            plan.task_c, plan.task_count, plan.c_cap, max_tasks, _local(plan))
+        want = fused_task_layout(plan.task_c, plan.task_count, plan.c_cap, max_tasks)
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+        assert got[2:] == want[2:] and got[3] == 0
+
+
+def _hand_built(lists):
+    """``(task_c, task_count, local, c_cap)`` from per-device
+    ``(rows, remote rows)``."""
+    c_cap = max(int(x.max()) + 1 for x, _ in lists)
+    t_cap = max(x.size for x, _ in lists)
+    task_c = np.full((len(lists), t_cap), c_cap, np.int32)
+    local = np.ones(task_c.shape, bool)
+    for p, (x, remote) in enumerate(lists):
+        task_c[p, : x.size] = x
+        local[p, : x.size] = ~np.isin(x, remote)
+    return task_c, np.array([x.size for x, _ in lists]), local, c_cap
+
+
+def _phase_problem(x, loc, seed, bs=16, n_store=6, rounds=2, cap_u=4):
+    """A device problem whose remote tasks read a receive buffer."""
+    rng = np.random.default_rng(seed)
+    T = x.size
+    f32 = lambda *s: rng.standard_normal(s).astype(np.float32)
+    a_src = np.where(loc, 0, rng.integers(1, rounds + 1, T)).astype(np.int32)
+    b_src = np.where(loc | (rng.random(T) < 0.5), 0,
+                     rng.integers(1, rounds + 1, T)).astype(np.int32)
+    off = lambda src: np.where(
+        src == 0, rng.integers(0, n_store, T), rng.integers(0, cap_u, T)
+    ).astype(np.int32)
+    return dict(
+        a_store=f32(n_store, bs, bs), a_recv=f32(rounds, cap_u, bs, bs),
+        b_store=f32(n_store, bs, bs), b_recv=f32(rounds, cap_u, bs, bs),
+        a_src=a_src, a_off=off(a_src), b_src=b_src, b_off=off(b_src),
+        c_idx=x, num_out=int(x.max()) + 1,
+    )
+
+
+@pytest.mark.parametrize("case", ["no_remote_row", "first_run_too_long", "no_local_row"])
+def test_two_phase_layout_hazards(case):
+    import functools
+    from types import SimpleNamespace
+
+    from repro.core.distributed import _fused_phases, fused_task_arrays
+
+    if case == "no_remote_row":
+        # device 0 is all local and the smallest (L1 = 3): its last run moves
+        # to phase 2, which every device needs; device 1's local rows 2 and
+        # 4 are surplus and go to phase 2 too
+        lists = [(np.array([0, 0, 1], np.int32), []),
+                 (np.array([0, 0, 0, 1, 2, 2, 3, 4, 4], np.int32), [1, 3])]
+        want_ph1 = [{0}, {0}]
+    elif case == "first_run_too_long":
+        # device 0's one local row has more tasks than L1 = 2: its phase-1
+        # call is empty and points at its first phase-2 row, a remote one
+        lists = [(np.array([0, 1, 1, 1, 1], np.int32), [0]),
+                 (np.array([0, 0, 1], np.int32), [1])]
+        want_ph1 = [set(), {0}]
+    else:
+        # device 1 has no all-local row: L1 == 0, today's layout
+        lists = [(np.array([0, 0, 1, 2, 2], np.int32), [1]),
+                 (np.array([0, 1, 1], np.int32), [0, 1])]
+        want_ph1 = [set(), set()]
+    task_c, count, local, c_cap = _hand_built(lists)
+    idx, real, lengths, n_local = fused_task_layout(task_c, count, c_cap, 5, local)
+    today = fused_task_layout(task_c, count, c_cap, 5)
+    n1 = sum(lengths[:n_local])
+    ph1 = [set(task_c[p, idx[p, :n1][real[p, :n1]]].tolist()) for p in range(2)]
+    assert ph1 == want_ph1
+    if case == "no_local_row":
+        assert n_local == 0
+        np.testing.assert_array_equal(idx, today[0])
+        np.testing.assert_array_equal(real, today[1])
+        assert lengths == today[2]
+    else:
+        assert n_local >= 1 and sum(lengths) <= sum(today[2]) + 4
+    qs = [_phase_problem(x, local[p, : x.size], seed=p)
+          for p, (x, _) in enumerate(lists)]
+    pad = lambda k: np.stack([np.pad(q[k], (0, task_c.shape[1] - q[k].size))
+                              for q in qs])
+    plan = SimpleNamespace(
+        task_a_src=pad("a_src"), task_a_off=pad("a_off"), task_b_src=pad("b_src"),
+        task_b_off=pad("b_off"), task_c=task_c, c_cap=c_cap)
+    laid = fused_task_arrays(plan, idx, n1)
+    # every phase-1 slot, padding included, reads the own store alone
+    assert (laid[0][:, :n1] == 0).all() and (laid[2][:, :n1] == 0).all()
+    spmm = functools.partial(fused_block_spmm, interpret=True)
+    phases = (lengths[:n_local], lengths[n_local:]) if n_local else (lengths,)
+    for p, q in enumerate(qs):
+        assert _accumulation_ok(laid[4][p], real[p], lengths)
+        got = np.asarray(_fused_phases(
+            spmm, q["a_store"], q["a_recv"], q["b_store"], q["b_recv"],
+            [x[p] for x in laid], c_cap, phases, on=real[p]))
+        used = np.unique(q["c_idx"])
+        np.testing.assert_array_equal(got[used], _fused(q, interpret=True)[used])

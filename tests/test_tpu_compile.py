@@ -28,7 +28,7 @@ BS = 512
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -41,8 +41,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @functools.lru_cache(maxsize=None)
@@ -110,3 +115,35 @@ def test_block_spmm_kernel_bs512(one_chip):
     step = functools.partial(block_spmm_kernel_call, num_out=tasks.num_out + 1)
     compiled = jax.jit(step).lower(data, data, *idx).compile()
     _assert_kernel(compiled)
+
+
+def test_four_chip_exchange_overlaps_phase_one(v5e_2x2, monkeypatch):
+    # the four-chip fused multiply, two-phase layout: XLA issues the
+    # ppermute rounds before the phase-1 kernel call and waits after it
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    import repro.core.distributed as D
+    import repro.kernels.ops as kops
+    from repro.core.families import band_block_coords
+    from repro.core.schedule import make_spgemm_plan
+
+    bs = 128
+    monkeypatch.setattr(kops, "_on_tpu", lambda: True)  # compile the kernel
+    # index arrays stay on the host: the chips are described, not attached
+    monkeypatch.setattr(jax, "device_put", lambda x, sharding=None: x)
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(4), (D.AXIS,))
+    coords = band_block_coords(24, 3)
+    plan = make_spgemm_plan(coords, coords, 4, bs, exchange="p2p")
+    exe = D.FusedSpgemmExecutable(plan, mesh)
+    assert len(exe.phases) == 2
+    sh = NamedSharding(mesh, PartitionSpec(D.AXIS))
+    store = _sds((4, plan.a_cap, bs, bs), jnp.float32, sh)
+    host = [_sds(x.shape, x.dtype, sh) for x in exe._idx_args + exe._send_args]
+    text = exe._mapped.lower(store, store, *host).compile().as_text()
+    lines = text[text.index("\nENTRY"):].splitlines()
+    at = lambda key: [i for i, l in enumerate(lines) if key in l]
+    kernels = at('custom_call_target="tpu_custom_call"')
+    starts, dones = at("collective-permute-start("), at("collective-permute-done(")
+    assert len(kernels) == 2 and starts and len(dones) == len(starts)
+    assert max(starts) < kernels[0] < min(dones) and max(dones) < kernels[1]
