@@ -39,7 +39,9 @@ operands between operations.  This package is that layer for the XLA mesh:
   feedback loop, and the resident re-layout collective
   :func:`dist_repartition` (planned ``ppermute`` rounds, block payloads
   only); the iterative drivers take ``rebalance=`` and re-lay iterates out
-  between iterations when the measured imbalance crosses the threshold.
+  between iterations when the measured imbalance crosses the threshold, and
+  :func:`relayout_for_product` lays operands out for one product and
+  returns the handles.
 """
 
 from .balance import (
@@ -48,6 +50,7 @@ from .balance import (
     WorkerLoad,
     owner_imbalance,
     rebalanced_owner,
+    relayout_for_product,
     worker_load,
 )
 from .cache import PlanCache
@@ -119,4 +122,5 @@ __all__ = [
     "worker_load",
     "owner_imbalance",
     "rebalanced_owner",
+    "relayout_for_product",
 ]

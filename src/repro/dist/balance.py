@@ -25,6 +25,10 @@ already materializes:
   migrate to the new owners entirely on device via planned ``ppermute``
   rounds; values, coordinates and Morton stack order are untouched, so the
   algorithm cannot observe the move — only the schedule can.
+* **Operands laid out for their product** (:func:`relayout_for_product`):
+  the single-multiply form of the loop, weighing each operand block by the
+  tasks that read it; ``dist_multiply(rebalance=)`` calls it per product,
+  and a caller that repeats the product holds the handles it returns.
 
 The iterative drivers (``dist_sp2_purify``, the inverse refinement loop, and
 ``dist_sqrt_inv_pipeline``) accept ``rebalance=RebalancePolicy(...)`` and run
@@ -38,14 +42,15 @@ import dataclasses
 
 import numpy as np
 
-from repro.core.quadtree import morton_encode
+from repro.core.quadtree import morton_encode, structure_fingerprint
 from repro.core.schedule import (
     SpgemmPlan,
     partition_morton,
     plan_worker_bytes,
     subtree_boundaries,
 )
-from repro.core.spgemm import Tasks
+from repro.core.spgemm import Tasks, spgemm_symbolic
+from repro.obs.tracer import tracer_of
 
 from .collectives import RepartitionExecutable, dist_repartition  # noqa: F401
 from .matrix import DistBSMatrix
@@ -62,6 +67,7 @@ __all__ = [
     "map_block_weights",
     "owner_imbalance",
     "rebalanced_owner",
+    "relayout_for_product",
     "dist_repartition",
     "RepartitionExecutable",
 ]
@@ -496,3 +502,47 @@ class LoadMonitor:
             return x, 0
         x, migrated, _ = self.migrate(x, w, cache)
         return x, migrated
+
+
+def relayout_for_product(
+    a: DistBSMatrix,
+    b: DistBSMatrix,
+    cache=None,
+    *,
+    policy: RebalancePolicy | None = None,
+) -> tuple[DistBSMatrix, DistBSMatrix]:
+    """Lay the operands of ``A @ B`` out for that product; returns ``(a, b)``.
+
+    Each operand block weighs the multiply tasks that read it plus one unit
+    of ownership; a squared operand (``b is a``) weighs its A-side and
+    B-side references together, as one store serves both.  An operand whose
+    owner map is within ``policy.threshold`` of balance under those weights
+    is returned untouched, as is one whose proposed cut would not gain
+    ``policy.min_gain`` (:meth:`LoadMonitor.propose`); otherwise its blocks
+    move to the weighted, subtree-aligned Morton cut on device
+    (:func:`dist_repartition`).  Everything is structural, so the decision
+    is the same for every call on the same structure, and repeated calls
+    are plan-cache hits; a caller that repeats the product holds the
+    returned handles and pays the move once.
+    """
+    policy = policy if policy is not None else RebalancePolicy()
+    tr = tracer_of(cache)
+    with tr.span("relayout_for_product", cat="migration",
+                 nnzb_a=a.nnzb, nnzb_b=b.nnzb):
+        key = ("spgemm-tasks", structure_fingerprint(a.codes(), b.codes(), a.bs))
+        build = lambda: spgemm_symbolic(a.coords, b.coords)
+        tasks = cache.get_or_build(key, build) if cache is not None else build()
+        wa, wb = block_reference_weights(tasks, a.nnzb, b.nnzb)
+        mon = LoadMonitor(a.nparts, policy)
+
+        def relayout(x, w):
+            w = w + 1.0
+            if owner_imbalance(x.owner, w, x.nparts) <= policy.threshold:
+                return x
+            new_owner = mon.propose(x, w)
+            return x if new_owner is None else dist_repartition(x, new_owner, cache)
+
+        if b is a:
+            a = relayout(a, wa + wb)
+            return a, a
+        return relayout(a, wa), relayout(b, wb)

@@ -37,15 +37,16 @@ from repro.core.distributed import (
 from repro.core.quadtree import build_quadtree_index, quadtree_depth
 from repro.core.schedule import (
     make_spgemm_plan,
+    plan_worker_bytes,
     structure_fingerprint,
 )
-from repro.core.spgemm import spamm_symbolic, spgemm_symbolic
+from repro.core.spgemm import spamm_symbolic
 from repro.kernels.precision import FP32, Precision, low_precision_task_mask
 from repro.obs.timing import timed_into
 from repro.obs.tracer import tracer_of
 
+from .balance import RebalancePolicy, relayout_for_product, worker_load
 from .cache import PlanCache
-from .collectives import dist_repartition
 from .matrix import (
     DistBSMatrix,
     _store_sharding,
@@ -129,9 +130,8 @@ def _plan_obs_static(plan) -> dict:
     """
     st = getattr(plan, "_obs_static", None)
     if st is None:
-        from .balance import RebalancePolicy, worker_load
-
         load = worker_load(plan)
+        _, _, recv_padded = plan_worker_bytes(plan)
         pol = RebalancePolicy()
         blk = plan.bs * plan.bs * 4
         rounds = []
@@ -154,6 +154,8 @@ def _plan_obs_static(plan) -> dict:
             full_costs=np.asarray(plan.task_count, np.float64) + base,
             full_tasks=int(np.asarray(plan.task_count).sum()),
             recv_sum=float(load.recv_bytes.sum()),
+            # what the SPMD program receives, padding included, over chips
+            recv_padded_sum=float(recv_padded.sum()),
             send_sum=float(load.send_bytes.sum()),
             rounds=rounds,
             tiles={},            # per-dtype pick_tiles memo
@@ -203,6 +205,7 @@ def _annotate_spgemm_dispatch(
         sp.worker_costs = tc.astype(np.float64) + st["base"]
         tasks = int(tc.sum())
     sp.args.update(tasks=tasks, recv_bytes=st["recv_sum"],
+                   recv_padded_bytes=st["recv_padded_sum"],
                    send_bytes=st["send_sum"])
     tr.counter("tasks_executed").add(float(tasks))
     before = getattr(exe, "tasks_before_exchange", None)
@@ -212,6 +215,7 @@ def _annotate_spgemm_dispatch(
         sp.args.update(tasks_before_exchange=n)
         tr.counter("tasks_before_exchange").add(float(n))
     tr.counter("recv_bytes").add(st["recv_sum"])
+    tr.counter("recv_padded_bytes").add(st["recv_padded_sum"])
     tr.counter("send_bytes").add(st["send_sum"])
     # exchange rounds run fused inside the jitted dispatch — emit honest
     # per-round markers carrying planned bytes, not fabricated durations.
@@ -284,44 +288,6 @@ def _check_operands(a: DistBSMatrix, b: DistBSMatrix) -> None:
     assert a.shape[1] == b.shape[0] and a.bs == b.bs, (a.shape, b.shape)
 
 
-def _rebalance_operands(
-    a: DistBSMatrix, b: DistBSMatrix, cache: PlanCache | None, policy
-) -> tuple[DistBSMatrix, DistBSMatrix]:
-    """Opt-in operand re-layout before planning a multiply.
-
-    Weighs each operand's current owner map against its task-reference
-    counts in this multiply (plus one unit of ownership weight per block) —
-    the :mod:`repro.dist.balance` cost model at single-op granularity — and
-    re-slots skewed operands through :func:`~repro.dist.collectives.
-    dist_repartition` before the plan is built.  Everything is structural,
-    so the decision is deterministic per structure pair and repeated calls
-    are pure cache hits; iterative callers should instead hold the
-    repartitioned handle (the drivers' ``rebalance=`` loop does).
-    """
-    from .balance import LoadMonitor, block_reference_weights, owner_imbalance
-
-    key = (
-        "spgemm-tasks",
-        structure_fingerprint(a.codes(), b.codes(), a.bs),
-    )
-    build = lambda: spgemm_symbolic(a.coords, b.coords)
-    tasks = cache.get_or_build(key, build) if cache is not None else build()
-    wa, wb = block_reference_weights(tasks, a.nnzb, b.nnzb)
-    wa += 1.0
-    wb += 1.0
-    mon = LoadMonitor(a.nparts, policy)
-    same = b is a
-
-    def relayout(x, w):
-        if owner_imbalance(x.owner, w, x.nparts) <= policy.threshold:
-            return x
-        new_owner = mon.propose(x, w)
-        return x if new_owner is None else dist_repartition(x, new_owner, cache)
-
-    a = relayout(a, wa)
-    return (a, a) if same else (a, relayout(b, wb))
-
-
 def _precision_of(precision, impl: str, exchange: str = "p2p") -> Precision:
     precision = FP32 if precision is None else precision
     if precision.is_mixed:
@@ -375,8 +341,9 @@ def dist_multiply(
     (``ref`` / ``kernel``) are fp32-only.
 
     ``rebalance`` (a :class:`repro.dist.balance.RebalancePolicy`) re-slots
-    skewed operand layouts on device before planning — see
-    :func:`_rebalance_operands`.
+    skewed operand layouts on device before planning, on every call — see
+    :func:`repro.dist.balance.relayout_for_product`, whose handles a caller
+    that repeats the product should hold instead.
     """
     _check_operands(a, b)
     precision = _precision_of(precision, impl, exchange)
@@ -386,7 +353,7 @@ def dist_multiply(
     with tr.span("dist_multiply", cat="collective",
                  nnzb_a=a.nnzb, nnzb_b=b.nnzb):
         if rebalance is not None:
-            a, b = _rebalance_operands(a, b, cache, rebalance)
+            a, b = relayout_for_product(a, b, cache, policy=rebalance)
 
         def build():
             plan = make_spgemm_plan(
@@ -538,9 +505,9 @@ def dist_spamm(
 
     ``rebalance`` (a :class:`repro.dist.balance.RebalancePolicy`) re-slots
     skewed operand layouts on device before planning
-    (:func:`_rebalance_operands`); note the stack-order norm tables are
-    layout-invariant, so prefetched ``a_norms`` / ``b_norms`` stay valid
-    across the re-layout.
+    (:func:`repro.dist.balance.relayout_for_product`); note the stack-order
+    norm tables are layout-invariant, so prefetched ``a_norms`` /
+    ``b_norms`` stay valid across the re-layout.
 
     ``precision`` (fused impl only) selects the leaf engine's dtype policy;
     ``adaptive`` rounds the smallest-bound kept tasks to bf16 under a budget
@@ -570,7 +537,7 @@ def _dist_spamm_impl(
 ):
     fused = _use_fused(impl, exchange)
     if rebalance is not None:
-        a, b = _rebalance_operands(a, b, cache, rebalance)
+        a, b = relayout_for_product(a, b, cache, policy=rebalance)
     # norm fetches stay outside the symbolic timer: a miss on the fused norm
     # executable is timed into cache.build_s by get_or_build
     if a_norms is None:

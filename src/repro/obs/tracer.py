@@ -18,7 +18,8 @@ service for the XLA-mesh reproduction:
 * **Counters and gauges** are registered once on the tracer's metrics
   registry (``plan_hits`` / ``plan_misses`` / ``tasks_executed`` /
   ``tasks_before_exchange`` (fused tasks laid out ahead of the exchange) /
-  ``recv_bytes`` / ``send_bytes`` / ``migrated_bytes`` /
+  ``recv_bytes`` / ``recv_padded_bytes`` (what the SPMD program receives,
+  padding included) / ``send_bytes`` / ``migrated_bytes`` /
   ``norm_fetch_bytes`` / ``host_fetches`` (blocking device-to-host reads),
   plus ``plans_verified`` / ``verify_violations``
   from the static verifier at plan-cache admission) and emitted uniformly:
