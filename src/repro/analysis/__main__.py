@@ -16,7 +16,7 @@ import numpy as np
 
 from ..core.schedule import make_spgemm_plan
 from . import lint as lint_mod
-from .mutate import CORRUPTIONS, NotApplicable
+from .mutate import CORRUPTIONS, SHARED_CORRUPTIONS, NotApplicable
 from .verify import verify_spgemm_plan, verify_task_mask
 
 # the benchmark structure families (benchmarks/spamm_sequences.py), scaled
@@ -53,6 +53,8 @@ def run_verify() -> int:
                                         exchange=exchange)
                 report = verify_spgemm_plan(plan)
                 if exchange == "p2p":
+                    report += verify_spgemm_plan(make_spgemm_plan(
+                        coords, coords, nparts, BS, shared_exchange=True))
                     rng = np.random.default_rng(nparts)
                     mask_on = rng.random(plan.tasks.num_tasks) < 0.5
                     report += verify_task_mask(plan, mask_on)
@@ -73,10 +75,13 @@ def run_verify() -> int:
 def run_selftest() -> int:
     coords = _coords(_structures()["random_offdiag"])
     plan = make_spgemm_plan(coords, coords, 4, BS)
+    shared = make_spgemm_plan(coords, coords, 4, BS, shared_exchange=True)
+    cases = [(n, c, plan) for n, c in CORRUPTIONS.items()]
+    cases += [(n, c, shared) for n, c in SHARED_CORRUPTIONS.items()]
     missed = 0
-    for name, (fn, expected) in CORRUPTIONS.items():
+    for name, (fn, expected), clean in cases:
         try:
-            bad, kwargs = fn(plan)
+            bad, kwargs = fn(clean)
         except NotApplicable as exc:
             print(f"MISS {name}: not applicable ({exc})")
             missed += 1

@@ -16,7 +16,7 @@ import numpy as np
 
 from ..core.schedule import SpgemmPlan
 
-__all__ = ["clone_plan", "CORRUPTIONS", "NotApplicable"]
+__all__ = ["clone_plan", "CORRUPTIONS", "SHARED_CORRUPTIONS", "NotApplicable"]
 
 
 class NotApplicable(RuntimeError):
@@ -168,6 +168,31 @@ def corrupt_accumulation_order(plan):
     raise NotApplicable("no output slot accumulates two tasks")
 
 
+def corrupt_shared_b_only(plan):
+    """Take out of a shared round a block that only B-side tasks on its
+    receiver read, its neighbour's slot sent again in its place (the only
+    block of a round is dropped): the B-side read is never delivered."""
+    p = clone_plan(plan)
+    if not p.exchange_shared:
+        raise NotApplicable("plan has no shared exchange")
+    t_owner = p.c_owner[p.tasks.c_idx]
+    for d in p.a_offsets:
+        for src in range(p.nparts):
+            mine = t_owner == (src + d) % p.nparts
+            b_only = np.setdiff1d(p.tasks.b_idx[mine], p.tasks.a_idx[mine])
+            cnt = int(p.a_send_count[d][src])
+            row = p.a_send[d][src]
+            hit = np.nonzero(np.isin(p.a_store_idx[src, row[:cnt]], b_only))[0]
+            if hit.size:
+                pos = int(hit[0])
+                if cnt == 1:
+                    p.a_send_count[d][src] = 0
+                else:
+                    row[pos] = row[pos - 1 if pos else 1]
+                return p, {}
+    raise NotApplicable("no shared round carries a block only B reads")
+
+
 # name -> (corruption, the check that must catch it)
 CORRUPTIONS = {
     "send_conflict": (corrupt_send_conflict, "send-conflict"),
@@ -179,4 +204,9 @@ CORRUPTIONS = {
     "mask_redirect": (corrupt_mask_redirect, "mask-redirect"),
     "capacity_mismatch": (corrupt_capacity_mismatch, "capacity-mismatch"),
     "accumulation_order": (corrupt_accumulation_order, "accumulation-order"),
+}
+
+# corruptions that need a shared-exchange plan (``shared_exchange=True``)
+SHARED_CORRUPTIONS = {
+    "shared_b_only": (corrupt_shared_b_only, "use-before-receive"),
 }

@@ -12,7 +12,8 @@ admitted to the cache:
   sender's real store, and the blocks delivered to a device within a round
   land in strictly increasing distinct receive slots (no two sends into one
   slot), never duplicating a block across rounds or re-delivering a block
-  the receiver owns.
+  the receiver owns.  A shared exchange (A @ A on one store) has A's rounds
+  only, and B's layout must be A's.
 * **Task addressing** — every task operand index resolves, in the staged
   ``[own store | recv per round]`` buffer layout, to exactly the global
   block the symbolic phase assigned it (anything undelivered is a
@@ -304,6 +305,23 @@ def _check_rounds(name, offsets, send, send_cnt, inv, owner, nparts, out):
                     seen[dst][g] = r
 
 
+def _check_shared(plan: SpgemmPlan, out):
+    """A shared exchange squares one store: B's layout is A's, and B has no
+    rounds of its own (its tasks address A's)."""
+    same = (np.array_equal(plan.a_owner, plan.b_owner)
+            and int(plan.a_cap) == int(plan.b_cap))
+    if not same or plan.b_offsets or plan.b_send or plan.b_send_count:
+        out.append(Violation(
+            "shared-exchange",
+            "plan shares one exchange between A and B, but "
+            + ("B's owner map or store capacity differs from A's"
+               if not same else
+               f"B keeps rounds of its own (offsets {tuple(plan.b_offsets)})"),
+            dict(b_offsets=[int(d) for d in plan.b_offsets],
+                 same_layout=bool(same)),
+        ))
+
+
 def _remote_refs(plan: SpgemmPlan, name: str):
     """Per-device remote operand references: (device, task slot, global task,
     round, sender, position-in-round) rows for every valid task whose
@@ -375,12 +393,20 @@ def verify_spgemm_plan(
     if plan.exchange == "p2p":
         _check_rounds("a", plan.a_offsets, plan.a_send, plan.a_send_count,
                       inv_a, np.asarray(plan.a_owner), P, out)
-        _check_rounds("b", plan.b_offsets, plan.b_send, plan.b_send_count,
-                      inv_b, np.asarray(plan.b_owner), P, out)
         buf_a, widths_a = _staged_buffer(inv_a, plan.a_cap, plan.a_offsets,
                                          plan.a_send, plan.a_send_count, P)
-        buf_b, widths_b = _staged_buffer(inv_b, plan.b_cap, plan.b_offsets,
-                                         plan.b_send, plan.b_send_count, P)
+        if plan.exchange_shared:
+            # B reads A's store and A's rounds: the addressing checks below
+            # then prove every B-side read delivered, by those rounds
+            _check_shared(plan, out)
+            buf_b, widths_b = buf_a, widths_a
+        else:
+            _check_rounds("b", plan.b_offsets, plan.b_send,
+                          plan.b_send_count, inv_b, np.asarray(plan.b_owner),
+                          P, out)
+            buf_b, widths_b = _staged_buffer(inv_b, plan.b_cap,
+                                             plan.b_offsets, plan.b_send,
+                                             plan.b_send_count, P)
     else:  # allgather baseline: [owner0 store | owner1 store | ...]
         buf_a = inv_a.reshape(1, -1).repeat(P, axis=0)
         buf_b = inv_b.reshape(1, -1).repeat(P, axis=0)

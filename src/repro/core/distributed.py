@@ -82,7 +82,9 @@ def _assemble_operands(a_store, b_store, a_and_b_sends, plan: SpgemmPlan):
     b_sends = a_and_b_sends[na:]
     if plan.exchange == "p2p":
         a_all = _exchange_bufs(a_store[0], plan.a_offsets, a_sends, plan.nparts)
-        b_all = _exchange_bufs(b_store[0], plan.b_offsets, b_sends, plan.nparts)
+        # A @ A on one store: the shared exchange serves both operands
+        b_all = a_all if plan.exchange_shared else _exchange_bufs(
+            b_store[0], plan.b_offsets, b_sends, plan.nparts)
     else:  # allgather baseline
         a_all = jax.lax.all_gather(a_store[0], AXIS).reshape(
             -1, *a_store.shape[-2:]
@@ -138,6 +140,18 @@ def _exchange_stack(store, offsets, send_pads, nparts, keeps=None, live=None):
             recv = jnp.pad(recv, ((0, pad), (0, 0), (0, 0)))
         bufs.append(recv)
     return jnp.stack(bufs, axis=0)
+
+
+def _own_stores(a_store, b_store, plan: SpgemmPlan, precision: Precision):
+    """This device's operand stores, cast before the exchange for bf16 (which
+    halves the ppermute payload too).  A shared exchange squares A, so its
+    B operand is A's store."""
+    a_own = a_store[0]
+    b_own = a_own if plan.exchange_shared else b_store[0]
+    if precision.mode == "bf16":
+        a_own = a_own.astype(jnp.bfloat16)
+        b_own = a_own if plan.exchange_shared else b_own.astype(jnp.bfloat16)
+    return a_own, b_own
 
 
 def _fused_spmm_fn(impl: str):
@@ -384,13 +398,10 @@ def _mapped_multiply_fused(
     trash row are materialized."""
     na = len(plan.a_offsets)
     a_sends, b_sends = a_and_b_sends[:na], a_and_b_sends[na:]
-    a_own, b_own = a_store[0], b_store[0]
-    if precision.mode == "bf16":
-        # cast before the exchange: halves the ppermute payload bytes too
-        a_own = a_own.astype(jnp.bfloat16)
-        b_own = b_own.astype(jnp.bfloat16)
+    a_own, b_own = _own_stores(a_store, b_store, plan, precision)
     a_recv = _exchange_stack(a_own, plan.a_offsets, a_sends, plan.nparts)
-    b_recv = _exchange_stack(b_own, plan.b_offsets, b_sends, plan.nparts)
+    b_recv = a_recv if plan.exchange_shared else _exchange_stack(
+        b_own, plan.b_offsets, b_sends, plan.nparts)
     tasks = (a_src_t[0], a_off_t[0], b_src_t[0], b_off_t[0], task_c[0])
     c = _fused_phases(
         _fused_spmm_fn(impl), a_own, a_recv, b_own, b_recv, tasks,
@@ -425,14 +436,11 @@ def _mapped_multiply_fused_masked(
     b_keeps = keeps_and_sends[na : na + nb]
     a_sends = keeps_and_sends[na + nb : 2 * na + nb]
     b_sends = keeps_and_sends[2 * na + nb :]
-    a_own, b_own = a_store[0], b_store[0]
-    if precision.mode == "bf16":
-        a_own = a_own.astype(jnp.bfloat16)
-        b_own = b_own.astype(jnp.bfloat16)
+    a_own, b_own = _own_stores(a_store, b_store, plan, precision)
     a_recv = _exchange_stack(
         a_own, plan.a_offsets, a_sends, plan.nparts, keeps=a_keeps, live=live_a
     )
-    b_recv = _exchange_stack(
+    b_recv = a_recv if plan.exchange_shared else _exchange_stack(
         b_own, plan.b_offsets, b_sends, plan.nparts, keeps=b_keeps, live=live_b
     )
     adaptive = precision.mode == "adaptive"
@@ -731,7 +739,17 @@ def _send_task_spans(plan: SpgemmPlan):
 def _exchange_keep_masks(plan: SpgemmPlan, keep_task: np.ndarray):
     """Per-round send keep masks + live round sets from a global kept-task
     mask.  Returns ``(a_keeps, b_keeps, live_a, live_b, stats)`` where each
-    keeps entry is ``[P, cap_d]`` bool and stats counts pruned payload."""
+    keeps entry is ``[P, cap_d]`` bool and stats counts pruned payload.
+
+    A shared exchange is refused: its send spans hold A-side readers only,
+    so a B-side read would be pruned (its masks would need both sides'
+    readers)."""
+    if plan.exchange_shared:
+        from ..analysis.errors import PlanError
+
+        raise PlanError(
+            "exchange pruning needs per-operand rounds; this plan shares one "
+            "exchange between A and B")
     maps = _send_task_spans(plan)
     nparts = plan.nparts
     keeps_by, live_by = {}, {}

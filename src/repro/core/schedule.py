@@ -191,6 +191,10 @@ class SpgemmPlan:
     task_a_off: np.ndarray | None = None
     task_b_src: np.ndarray | None = None
     task_b_off: np.ndarray | None = None
+    # A @ A on one resident store: the A rounds ship the union of both
+    # sides' needs, B has no rounds of its own (b_offsets == ()), and B's
+    # indices address A's receive buffers
+    exchange_shared: bool = False
 
     @property
     def shapes(self):
@@ -293,6 +297,7 @@ def make_spgemm_plan(
     a_owner: np.ndarray | None = None,
     b_owner: np.ndarray | None = None,
     align_subtrees: bool = True,
+    shared_exchange: bool = False,
 ) -> SpgemmPlan:
     """Plan a distributed multiply: placement, task schedule, exchange.
 
@@ -302,6 +307,11 @@ def make_spgemm_plan(
     ``tasks`` pins a precomputed (possibly SpAMM-pruned) task list so the
     symbolic phase is not redone.  ``align_subtrees`` snaps Morton partition
     cuts to quadtree node boundaries within the balance slack.
+
+    ``shared_exchange`` plans A @ A on one store (p2p only; A and B must
+    have equal coords and owners): one set of rounds ships, per device, the
+    union of the blocks its tasks read on either side, so a block both
+    sides need crosses the wire once (``SpgemmPlan.exchange_shared``).
     """
     tasks = tasks if tasks is not None else spgemm_symbolic(a_coords, b_coords)
     na, nb, nc = a_coords.shape[0], b_coords.shape[0], tasks.num_out
@@ -350,6 +360,14 @@ def make_spgemm_plan(
             raise PlanError(
                 f"{name}_owner assigns blocks outside the mesh of {nparts} "
                 f"(owner range [{int(owner.min())}, {int(owner.max())}])")
+    if shared_exchange and (
+        exchange != "p2p"
+        or not np.array_equal(a_coords, b_coords)
+        or not np.array_equal(a_owner, b_owner)
+    ):
+        raise PlanError(
+            "a shared exchange squares one store: it needs the p2p exchange "
+            "and equal A and B coords and owner maps")
 
     a_slot, a_stores = _owner_slots(a_owner, nparts)
     b_slot, b_stores = _owner_slots(b_owner, nparts)
@@ -377,21 +395,30 @@ def make_spgemm_plan(
     # For matrix X in {A, B}: device p needs the distinct X blocks referenced
     # by its tasks; those owned elsewhere arrive via the rounds planned by
     # plan_fetch.
-    def _exchange(x_owner, x_slot, ref_idx):
-        needs = [
-            np.unique(ref_idx[t_owner == p]) if np.any(t_owner == p) else np.zeros(0, np.int64)
-            for p in range(nparts)
-        ]
-        return plan_fetch(x_owner, x_slot, needs, nparts)
+    def _needs(*ref_idx):
+        return [np.unique(np.concatenate([r[t_owner == p] for r in ref_idx]))
+                for p in range(nparts)]
 
-    if exchange == "p2p":
-        a_offsets, a_send, a_send_cnt, a_recv_pos = _exchange(a_owner, a_slot, tasks.a_idx)
-        b_offsets, b_send, b_send_cnt, b_recv_pos = _exchange(b_owner, b_slot, tasks.b_idx)
+    if exchange == "p2p" and shared_exchange:
+        a_offsets, a_send, a_send_cnt, a_recv_pos = plan_fetch(
+            a_owner, a_slot, _needs(tasks.a_idx, tasks.b_idx), nparts)
+        b_offsets, b_send, b_send_cnt = (), {}, {}
+    elif exchange == "p2p":
+        a_offsets, a_send, a_send_cnt, a_recv_pos = plan_fetch(
+            a_owner, a_slot, _needs(tasks.a_idx), nparts)
+        b_offsets, b_send, b_send_cnt, b_recv_pos = plan_fetch(
+            b_owner, b_slot, _needs(tasks.b_idx), nparts)
     else:  # allgather baseline: no planned exchange, full replication
         a_offsets = b_offsets = ()
         a_send = b_send = {}
         a_send_cnt = b_send_cnt = {}
         a_recv_pos = b_recv_pos = {}
+    # where B's tasks find their blocks: a shared exchange's are A's rounds
+    b_rounds = (
+        (a_owner, a_slot, a_offsets, a_send, a_recv_pos, a_cap)
+        if shared_exchange
+        else (b_owner, b_slot, b_offsets, b_send, b_recv_pos, b_cap)
+    )
 
     # -- device-local operand indices ----------------------------------------
     # local buffer layout: [store (cap) | offset buffers in tuple order]
@@ -421,10 +448,7 @@ def make_spgemm_plan(
             dtype=np.int32,
         )
         tb = np.array(
-            [
-                local_index(b_owner, b_slot, b_offsets, b_send, b_recv_pos, b_cap, g, p)
-                for g in tasks.b_idx[sel]
-            ],
+            [local_index(*b_rounds, g, p) for g in tasks.b_idx[sel]],
             dtype=np.int32,
         )
         tc = c_slot[tasks.c_idx[sel]].astype(np.int32)
@@ -444,8 +468,9 @@ def make_spgemm_plan(
         task_a_src, task_a_off = split_local_indices(
             task_a, a_cap, [a_send[d].shape[1] for d in a_offsets]
         )
+        _, _, offsets, send, _, cap = b_rounds
         task_b_src, task_b_off = split_local_indices(
-            task_b, b_cap, [b_send[d].shape[1] for d in b_offsets]
+            task_b, cap, [send[d].shape[1] for d in offsets]
         )
 
     return SpgemmPlan(
@@ -485,6 +510,7 @@ def make_spgemm_plan(
         task_a_off=task_a_off,
         task_b_src=task_b_src,
         task_b_off=task_b_off,
+        exchange_shared=shared_exchange,
     )
 
 
@@ -551,7 +577,8 @@ def plan_byte_provenance(plan: SpgemmPlan) -> dict:
       block counts, for the executed-task-graph analyzer.
     * ``fetch_a`` / ``fetch_b`` — flat ``(gids, src, dst)`` arrays: global
       block index, owning worker, fetching worker for every planned remote
-      reference — the per-block movement-lineage feed.
+      reference — the per-block movement-lineage feed.  A shared exchange
+      reads one store: its blocks count once, under ``fetch_a``.
 
     All quantities are static plan properties; delta-mask pruning and bf16
     wire halving are applied by the ledger at dispatch time.
@@ -564,13 +591,18 @@ def plan_byte_provenance(plan: SpgemmPlan) -> dict:
     local = np.zeros(P, dtype=np.float64)
     shipped = np.zeros(P, dtype=np.float64)
     fetch = {}
-    for name, owner, ref_idx in (
-        ("a", plan.a_owner, tasks.a_idx),
-        ("b", plan.b_owner, tasks.b_idx),
-    ):
+    sides = (
+        ("a", plan.a_owner, (tasks.a_idx,)),
+        ("b", plan.b_owner, (tasks.b_idx,)),
+    )
+    if plan.exchange_shared:  # one store: a block read on both sides counts once
+        sides = (("a", plan.a_owner, (tasks.a_idx, tasks.b_idx)),
+                 ("b", plan.b_owner, ()))
+    for name, owner, ref_idx in sides:
         gids_l, src_l, dst_l = [], [], []
         for p in range(P):
-            refs = np.unique(ref_idx[t_owner == p]) if ref_idx.size else np.zeros(0, np.int64)
+            refs = np.unique(np.concatenate(
+                [np.zeros(0, np.int64)] + [r[t_owner == p] for r in ref_idx]))
             own = int((owner[refs] == p).sum()) if refs.size else 0
             referenced[p] += refs.size * blk
             local[p] += own * blk

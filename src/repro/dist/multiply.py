@@ -69,6 +69,12 @@ _resident_block_norms = resident_block_norms
 _FUSED_IMPLS = ("fused", "fused-interpret")
 
 
+def _shares_exchange(a: DistBSMatrix, b: DistBSMatrix, exchange: str) -> bool:
+    """A @ A on one resident store ships each block once
+    (``make_spgemm_plan(shared_exchange=True)``)."""
+    return b is a and exchange == "p2p"
+
+
 def multiply_plan_key(
     a: DistBSMatrix,
     b: DistBSMatrix,
@@ -81,6 +87,8 @@ def multiply_plan_key(
 
     Operand dtypes and the precision policy are part of the key — a bf16 or
     adaptive program is a different compiled artifact than the fp32 one.
+    So is a shared exchange (``b is a``): its plan squares A's store, and a
+    B with A's structure and owners but another store must not reuse it.
     """
     return (
         "spgemm",
@@ -93,6 +101,7 @@ def multiply_plan_key(
         str(a.dtype),
         str(b.dtype),
         precision.key(),
+        _shares_exchange(a, b, exchange),
     )
 
 
@@ -206,8 +215,11 @@ def _annotate_spgemm_dispatch(
         tasks = int(tc.sum())
     sp.args.update(tasks=tasks, recv_bytes=st["recv_sum"],
                    recv_padded_bytes=st["recv_padded_sum"],
-                   send_bytes=st["send_sum"])
+                   send_bytes=st["send_sum"],
+                   exchange_shared=plan.exchange_shared)
     tr.counter("tasks_executed").add(float(tasks))
+    if plan.exchange_shared:
+        tr.counter("shared_exchanges").add(1.0)
     before = getattr(exe, "tasks_before_exchange", None)
     if before is not None:
         # fused layouts: tasks laid out ahead of the first received block
@@ -364,6 +376,7 @@ def dist_multiply(
                 exchange=exchange,
                 a_owner=a.owner,
                 b_owner=b.owner,
+                shared_exchange=_shares_exchange(a, b, exchange),
             )
             # the pinned placements must reproduce the operands' resident
             # layout
@@ -559,7 +572,8 @@ def _dist_spamm_impl(
         def build():
             # the delta plan IS the exact-multiply plan; reuse one already
             # cached for dist_multiply on this structure instead of redoing
-            # the symbolic phase (only the executable differs)
+            # the symbolic phase (only the executable differs) — unless it
+            # shares its exchange, which the masked exchange cannot prune
             exact = (
                 cache.peek(multiply_plan_key(
                     a, b, exchange=exchange, impl=impl, precision=precision
@@ -567,6 +581,8 @@ def _dist_spamm_impl(
                 if cache is not None
                 else None
             )
+            if exact is not None and exact[0].exchange_shared:
+                exact = None
             plan = exact[0] if exact is not None else make_spgemm_plan(
                 a.coords,
                 b.coords,

@@ -25,7 +25,12 @@ from repro.analysis.lint import (
     lint_paths,
     load_baseline,
 )
-from repro.analysis.mutate import CORRUPTIONS, NotApplicable, clone_plan
+from repro.analysis.mutate import (
+    CORRUPTIONS,
+    SHARED_CORRUPTIONS,
+    NotApplicable,
+    clone_plan,
+)
 from repro.analysis.verify import (
     verify_add_plan,
     verify_compact_plan,
@@ -74,6 +79,51 @@ def test_mutation_caught_with_provenance(name):
     assert all(v.message for v in caught)
     # the corruption never leaked into the original plan
     assert verify_spgemm_plan(plan) == []
+
+
+@pytest.mark.parametrize("name", sorted(CORRUPTIONS) + sorted(SHARED_CORRUPTIONS))
+def test_shared_plan_mutation_caught_with_provenance(name):
+    # A·A with one shared exchange: the same invariants hold for its rounds,
+    # and a block only the B side reads must still arrive
+    plan = _plan(shared_exchange=True)
+    assert plan.exchange_shared and plan.b_offsets == () and plan.a_offsets
+    assert verify_spgemm_plan(plan) == []
+    fn, expected = {**CORRUPTIONS, **SHARED_CORRUPTIONS}[name]
+    bad, kwargs = fn(plan)
+    caught = [v for v in verify_spgemm_plan(bad, **kwargs) if v.check == expected]
+    assert caught and all(v.provenance and v.message for v in caught)
+    assert verify_spgemm_plan(plan) == []
+
+
+def test_shared_corruption_needs_a_shared_plan():
+    with pytest.raises(NotApplicable):
+        SHARED_CORRUPTIONS["shared_b_only"][0](_plan())
+
+
+def test_shared_layout_violation_reported():
+    # B keeping rounds of its own beside a shared exchange is refused
+    plan = _plan(shared_exchange=True)
+    two = _plan()
+    bad = clone_plan(plan)
+    for attr in ("b_offsets", "b_send", "b_send_count"):
+        object.__setattr__(bad, attr, getattr(two, attr))
+    assert "shared-exchange" in {v.check for v in verify_spgemm_plan(bad)}
+
+
+def test_shared_exchange_refuses_two_stores_and_exchange_pruning():
+    from repro.core.distributed import _exchange_keep_masks
+
+    m = random_block_matrix(256, BS, 0.25, seed=3)
+    owner = np.arange(m.coords.shape[0], dtype=np.int32) % 4
+    with pytest.raises(PlanError, match="shared exchange"):
+        make_spgemm_plan(m.coords, m.coords, 4, BS, shared_exchange=True,
+                         a_owner=owner, b_owner=owner[::-1].copy())
+    with pytest.raises(PlanError, match="shared exchange"):
+        make_spgemm_plan(m.coords, m.coords, 4, BS, shared_exchange=True,
+                         exchange="allgather")
+    plan = _plan(shared_exchange=True)
+    with pytest.raises(PlanError, match="shares one exchange"):
+        _exchange_keep_masks(plan, np.ones(plan.tasks.num_tasks, bool))
 
 
 def test_mutation_suite_covers_required_corruptions():
@@ -395,6 +445,38 @@ def test_fuzz_pinned_plans_verify_and_ledger_matches_worker_bytes(
         assert np.array_equal(prov["shipped"], recv)
     assert prov["task_local"].shape == (nparts, plan.task_gidx.shape[1])
     assert np.array_equal(prov["task_local"].sum(axis=1), prov["local_tasks"])
+
+
+@settings(max_examples=20, deadline=None)
+@given(
+    seed=st.integers(0, 10**6),
+    nparts=st.sampled_from([1, 2, 3, 4, 8]),
+    density=st.floats(0.05, 0.6),
+    pin=st.sampled_from(["morton", "skew", "random"]),
+)
+def test_fuzz_shared_plans_verify_and_ship_the_union(seed, nparts, density, pin):
+    m = random_block_matrix(128, BS, density, seed=seed)
+    nb = m.coords.shape[0]
+    owner = {"skew": np.zeros(nb, np.int32),
+             "random": np.random.default_rng(seed).integers(
+                 0, nparts, nb).astype(np.int32),
+             "morton": None}[pin]
+    kw = dict(a_owner=owner, b_owner=owner)
+    plan = make_spgemm_plan(m.coords, m.coords, nparts, BS,
+                            shared_exchange=True, **kw)
+    two = make_spgemm_plan(m.coords, m.coords, nparts, BS, **kw)
+    assert verify_spgemm_plan(plan) == []
+    # one set of rounds, the union of both sides' needs: never more than
+    # the two exchanges receive, and the ledger still conserves
+    recv, send, padded = plan_worker_bytes(plan)
+    recv2, _, padded2 = plan_worker_bytes(two)
+    assert (recv <= recv2).all() and (padded <= padded2).all()
+    prov = plan_byte_provenance(plan)
+    assert np.array_equal(prov["local"] + prov["shipped"], prov["referenced"])
+    assert np.array_equal(prov["shipped"], recv)
+    assert np.array_equal(prov["wire_send"], send)
+    # the same tasks in the same order: C's bits cannot change
+    assert np.array_equal(plan.task_gidx, two.task_gidx)
 
 
 # ---------------------------------------------------------------------------

@@ -117,9 +117,8 @@ def test_block_spmm_kernel_bs512(one_chip):
     _assert_kernel(compiled)
 
 
-def test_four_chip_exchange_overlaps_phase_one(v5e_2x2, monkeypatch):
-    # the four-chip fused multiply, two-phase layout: XLA issues the
-    # ppermute rounds before the phase-1 kernel call and waits after it
+def _four_chip_entry(v5e_2x2, monkeypatch, **plan_kw):
+    """The four-chip fused multiply of a band, compiled: (plan, ENTRY lines)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -134,16 +133,37 @@ def test_four_chip_exchange_overlaps_phase_one(v5e_2x2, monkeypatch):
     monkeypatch.setattr(jax, "device_put", lambda x, sharding=None: x)
     mesh = Mesh(np.array(v5e_2x2.devices).reshape(4), (D.AXIS,))
     coords = band_block_coords(24, 3)
-    plan = make_spgemm_plan(coords, coords, 4, bs, exchange="p2p")
+    plan = make_spgemm_plan(coords, coords, 4, bs, exchange="p2p", **plan_kw)
     exe = D.FusedSpgemmExecutable(plan, mesh)
     assert len(exe.phases) == 2
     sh = NamedSharding(mesh, PartitionSpec(D.AXIS))
     store = _sds((4, plan.a_cap, bs, bs), jnp.float32, sh)
     host = [_sds(x.shape, x.dtype, sh) for x in exe._idx_args + exe._send_args]
     text = exe._mapped.lower(store, store, *host).compile().as_text()
-    lines = text[text.index("\nENTRY"):].splitlines()
+    return plan, text[text.index("\nENTRY"):].splitlines()
+
+
+def _assert_overlaps_phase_one(lines):
+    # XLA issues the ppermute rounds before the phase-1 kernel call and
+    # waits after it
     at = lambda key: [i for i, l in enumerate(lines) if key in l]
     kernels = at('custom_call_target="tpu_custom_call"')
     starts, dones = at("collective-permute-start("), at("collective-permute-done(")
     assert len(kernels) == 2 and starts and len(dones) == len(starts)
     assert max(starts) < kernels[0] < min(dones) and max(dones) < kernels[1]
+    return starts
+
+
+def test_four_chip_exchange_overlaps_phase_one(v5e_2x2, monkeypatch):
+    # the four-chip fused multiply, two-phase layout
+    _, lines = _four_chip_entry(v5e_2x2, monkeypatch)
+    _assert_overlaps_phase_one(lines)
+
+
+def test_four_chip_shared_exchange_runs_once(v5e_2x2, monkeypatch):
+    # A·A with one shared exchange: one ppermute per round, not two, and
+    # still behind the phase-1 kernel call
+    plan, lines = _four_chip_entry(v5e_2x2, monkeypatch, shared_exchange=True)
+    assert plan.exchange_shared and plan.b_offsets == ()
+    starts = _assert_overlaps_phase_one(lines)
+    assert len(starts) == len(plan.a_offsets)
